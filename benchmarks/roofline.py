@@ -82,12 +82,12 @@ def markdown(rows):
     return "\n".join(out)
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--in", dest="inputs", nargs="*",
                     default=["experiments/dryrun.jsonl"])
     ap.add_argument("--mesh", default="single")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     recs = load(args.inputs)
     rows = table(recs, args.mesh)
     if not rows:

@@ -95,10 +95,10 @@ def main():
                           seeds=(0, 1, 2, 3, 4) if args.full else (0, 1))
         print()
     if on("roofline"):
-        import subprocess
-        import sys
-        subprocess.run([sys.executable, "-m", "benchmarks.roofline"],
-                       check=False)
+        # in this process: a child started after JAX is loaded here could
+        # not take the chip this process holds
+        from benchmarks import roofline
+        roofline.main([])
     elapsed = time.time() - t0
     print(f"\n# benchmarks done in {elapsed:.0f}s")
 

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 F32 = jnp.float32
 NEG = -1e30
 
@@ -72,7 +74,7 @@ def _fwd_kernel(h_ref, w_ref, tok_ref, logp_ref, logz_ref, ent_ref,
 
 
 def fwd_pallas(hidden, w, tokens, *, block_n: int = 256, block_v: int = 512,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """hidden: (N, D), w: (D, V), tokens: (N,) -> (logp, logz, ent) f32."""
     n, d = hidden.shape
     v = w.shape[1]
@@ -91,7 +93,7 @@ def fwd_pallas(hidden, w, tokens, *, block_n: int = 256, block_v: int = 512,
         out_specs=[pl.BlockSpec((block_n,), lambda i, j: (i,))] * 3,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_n,), F32)] * 4,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(hidden, w, tokens)
 
 
@@ -121,7 +123,7 @@ def _bwd_dh_kernel(h_ref, w_ref, tok_ref, logz_ref, g_ref, dh_ref, acc_sc,
 
 
 def bwd_dh_pallas(hidden, w, tokens, logz, g, *, block_n: int = 256,
-                  block_v: int = 512, interpret: bool = True):
+                  block_v: int = 512, interpret: bool | None = None):
     n, d = hidden.shape
     v = w.shape[1]
     num_n, num_v = n // block_n, v // block_v
@@ -139,7 +141,7 @@ def bwd_dh_pallas(hidden, w, tokens, logz, g, *, block_n: int = 256,
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), hidden.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, d), F32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(hidden, w, tokens, logz, g)
 
 
@@ -170,7 +172,7 @@ def _bwd_dw_kernel(h_ref, w_ref, tok_ref, logz_ref, g_ref, dw_ref, acc_sc,
 
 
 def bwd_dw_pallas(hidden, w, tokens, logz, g, *, block_n: int = 256,
-                  block_v: int = 512, interpret: bool = True):
+                  block_v: int = 512, interpret: bool | None = None):
     n, d = hidden.shape
     v = w.shape[1]
     num_n, num_v = n // block_n, v // block_v
@@ -188,5 +190,5 @@ def bwd_dw_pallas(hidden, w, tokens, logz, g, *, block_n: int = 256,
         out_specs=pl.BlockSpec((d, block_v), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((d, v), w.dtype),
         scratch_shapes=[pltpu.VMEM((d, block_v), F32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(hidden, w, tokens, logz, g)

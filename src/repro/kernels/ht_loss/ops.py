@@ -20,7 +20,7 @@ F32 = jnp.float32
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def fused_token_logprobs(hidden, w, tokens, block_n: int = 256,
-                         block_v: int = 512, interpret: bool = True):
+                         block_v: int = 512, interpret: bool | None = None):
     """hidden: (N, D), w: (D, V), tokens: (N,) -> (logp (N,), entropy (N,)).
 
     Gradients flow to ``hidden`` and ``w`` through logp only.
@@ -51,7 +51,7 @@ fused_token_logprobs.defvjp(_fwd, _bwd)
 
 
 def fused_score_grid(hidden, w, tokens, *, block_n: int = 128,
-                     block_v: int = 512, interpret: bool = True):
+                     block_v: int = 512, interpret: bool | None = None):
     """(B, T) grid convenience wrapper: scores tokens[:, 1:] from
     hidden[:, :-1] like ``score_tokens`` and left-pads — returns
     (logp (B, T), entropy (B, T))."""

@@ -36,7 +36,7 @@ F32 = jnp.float32
 
 
 def paged_attention(q, k_pages, v_pages, pos_pages, block_tables, q_pos,
-                    *, interpret: bool = True):
+                    *, interpret: bool | None = None):
     """q: (S, H, D) flat query heads; k_pages/v_pages: (P, page_len, KV, D);
     pos_pages: (P, page_len); block_tables: (S, M); q_pos: (S,).
     Returns out (S, H, D)."""
@@ -51,7 +51,7 @@ def paged_attention(q, k_pages, v_pages, pos_pages, block_tables, q_pos,
 
 def paged_mla_attention(q_abs, q_rope, c_pages, kr_pages, pos_pages,
                         block_tables, q_pos, *, scale: float,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """MLA variant: the latent pool is MQA-shaped (no kv-head axis, no GQA
     regrouping) and the value operand IS the latent page, so the kernel's
     output stays in latent rank R — the caller applies W_uv / W_o.
@@ -66,7 +66,7 @@ def paged_mla_attention(q_abs, q_rope, c_pages, kr_pages, pos_pages,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
 def paged_prefill_attention(q, k, v, segment_ids, seg_start, block_tables,
                             k_pages, v_pages, pos_pages, bq=16, bk=16,
-                            interpret=True):
+                            interpret=None):
     """Fused pool+suffix prefill attention with an exact custom vjp.
 
     q (R, H, T, D) / k, v (R, KV, T, D): PagedLayout suffix batch;
@@ -136,7 +136,7 @@ paged_prefill_attention.defvjp(_prefill_fwd, _prefill_bwd)
 def paged_prefill_attention_bthd(q, k, v, segment_ids, seg_start,
                                  block_tables, k_pages, v_pages, pos_pages,
                                  *, bq: int = 16, bk: int = 16,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Convenience wrapper taking the model layout q (R, T, H, D) /
     k, v (R, T, KV, D); transposes around the kernel layout (the
     transposes sit outside the custom_vjp and differentiate fine)."""

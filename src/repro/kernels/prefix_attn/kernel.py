@@ -35,8 +35,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 F32 = jnp.float32
 NEG = -1e30
+
+
+def _rows(x):
+    """``(..., N)`` -> ``(..., 1, N)``.  A block of per-token values (LSE,
+    delta, segment ids, page positions) then has a second-minor dim equal
+    to the array's, as Mosaic requires; the lane dim stays the block size,
+    so on a TPU that must be a multiple of 128 or the whole axis."""
+    return x[..., None, :]
 
 
 def _block_mask(q0, k0, bq, bk, cut, window):
@@ -102,7 +112,7 @@ def _fwd_kernel(cut_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def fwd_pallas(q, k, v, cut_lens, *, window: int = 0, bq: int = 128,
-               bk: int = 128, interpret: bool = True):
+               bk: int = 128, interpret: bool | None = None):
     b, h, t, d = q.shape
     kvh = k.shape[1]
     g = h // kvh
@@ -139,7 +149,7 @@ def fwd_pallas(q, k, v, cut_lens, *, window: int = 0, bq: int = 128,
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, t), F32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cut_lens, q, k, v)
     return out
 
@@ -218,7 +228,7 @@ def _bwd_dkv_kernel(cut_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def bwd_pallas(q, k, v, o, lse, do, cut_lens, *, window: int = 0,
-               bq: int = 128, bk: int = 128, interpret: bool = True):
+               bq: int = 128, bk: int = 128, interpret: bool | None = None):
     """Returns (dq (B,H,T,D), dk (B,H,T,D), dv (B,H,T,D)) — dk/dv are
     PER-QUERY-HEAD here; ops.py reduces them over GQA groups."""
     b, h, t, d = q.shape
@@ -249,7 +259,7 @@ def bwd_pallas(q, k, v, o, lse, do, cut_lens, *, window: int = 0,
             scratch_shapes=[pltpu.VMEM((bq, d), F32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cut_lens, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -278,7 +288,7 @@ def bwd_pallas(q, k, v, o, lse, do, cut_lens, *, window: int = 0,
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cut_lens, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -356,7 +366,7 @@ def seg_block_ranges(segment_ids, blk: int):
 
 
 def packed_fwd_pallas(q, k, v, segment_ids, *, bq: int = 128, bk: int = 128,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     b, h, t, d = q.shape
     kvh = k.shape[1]
     g = h // kvh
@@ -401,7 +411,7 @@ def packed_fwd_pallas(q, k, v, segment_ids, *, bq: int = 128, bk: int = 128,
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, t), F32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lo, hi, q, k, v, segment_ids, segment_ids)
     return out
 
@@ -424,11 +434,12 @@ def _packed_bwd_dq_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, do_ref,
         k = k_ref[0, 0].astype(F32)
         v = v_ref[0, 0].astype(F32)
         do = do_ref[0, 0].astype(F32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0, 0]
+        delta = delta_ref[0, 0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 precision=jax.lax.Precision.HIGHEST) * scale
-        mask = _packed_mask(qi * bq, ki * bk, bq, bk, segq_ref[0], segk_ref[0])
+        mask = _packed_mask(qi * bq, ki * bk, bq, bk, segq_ref[0, 0],
+                            segk_ref[0, 0])
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  precision=jax.lax.Precision.HIGHEST)
@@ -459,11 +470,12 @@ def _packed_bwd_dkv_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, do_ref,
         k = k_ref[0, 0].astype(F32)
         v = v_ref[0, 0].astype(F32)
         do = do_ref[0, 0].astype(F32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0, 0]
+        delta = delta_ref[0, 0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 precision=jax.lax.Precision.HIGHEST) * scale
-        mask = _packed_mask(qi * bq, ki * bk, bq, bk, segq_ref[0], segk_ref[0])
+        mask = _packed_mask(qi * bq, ki * bk, bq, bk, segq_ref[0, 0],
+                            segk_ref[0, 0])
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)          # (bq, bk)
         dv_sc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                           precision=jax.lax.Precision.HIGHEST)
@@ -480,7 +492,7 @@ def _packed_bwd_dkv_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def packed_bwd_pallas(q, k, v, o, lse, do, segment_ids, *, bq: int = 128,
-                      bk: int = 128, interpret: bool = True):
+                      bk: int = 128, interpret: bool | None = None):
     """Returns (dq (B,H,T,D), dk (B,H,T,D), dv (B,H,T,D)) — dk/dv are
     PER-QUERY-HEAD here; ops.py reduces them over GQA groups."""
     b, h, t, d = q.shape
@@ -509,14 +521,14 @@ def packed_bwd_pallas(q, k, v, o, lse, do, segment_ids, *, bq: int = 128,
                              (b_, h_ // g, ki, 0)),
                 pl.BlockSpec((1, 1, bq, d),
                              lambda b_, h_, qi, ki, lo_, hi_: (b_, h_, qi, 0)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b_, h_, qi, ki, lo_, hi_: (b_, h_, 0, qi)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b_, h_, qi, ki, lo_, hi_: (b_, h_, 0, qi)),
                 pl.BlockSpec((1, 1, bq),
-                             lambda b_, h_, qi, ki, lo_, hi_: (b_, h_, qi)),
-                pl.BlockSpec((1, 1, bq),
-                             lambda b_, h_, qi, ki, lo_, hi_: (b_, h_, qi)),
-                pl.BlockSpec((1, bq),
-                             lambda b_, h_, qi, ki, lo_, hi_: (b_, qi)),
-                pl.BlockSpec((1, bk),
-                             lambda b_, h_, qi, ki, lo_, hi_: (b_, ki)),
+                             lambda b_, h_, qi, ki, lo_, hi_: (b_, 0, qi)),
+                pl.BlockSpec((1, 1, bk),
+                             lambda b_, h_, qi, ki, lo_, hi_: (b_, 0, ki)),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, bq, d),
@@ -524,8 +536,9 @@ def packed_bwd_pallas(q, k, v, o, lse, do, segment_ids, *, bq: int = 128,
             scratch_shapes=[pltpu.VMEM((bq, d), F32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-        interpret=interpret,
-    )(lo, hi, q, k, v, do, lse, delta, segment_ids, segment_ids)
+        interpret=resolve_interpret(interpret),
+    )(lo, hi, q, k, v, do, _rows(lse), _rows(delta), _rows(segment_ids),
+      _rows(segment_ids))
 
     dk, dv = pl.pallas_call(
         functools.partial(_packed_bwd_dkv_kernel, bq=bq, bk=bk, nq=nq,
@@ -544,14 +557,14 @@ def packed_bwd_pallas(q, k, v, o, lse, do, segment_ids, *, bq: int = 128,
                              (b_, h_ // g, ki, 0)),
                 pl.BlockSpec((1, 1, bq, d),
                              lambda b_, h_, ki, qi, lo_, hi_: (b_, h_, qi, 0)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b_, h_, ki, qi, lo_, hi_: (b_, h_, 0, qi)),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda b_, h_, ki, qi, lo_, hi_: (b_, h_, 0, qi)),
                 pl.BlockSpec((1, 1, bq),
-                             lambda b_, h_, ki, qi, lo_, hi_: (b_, h_, qi)),
-                pl.BlockSpec((1, 1, bq),
-                             lambda b_, h_, ki, qi, lo_, hi_: (b_, h_, qi)),
-                pl.BlockSpec((1, bq),
-                             lambda b_, h_, ki, qi, lo_, hi_: (b_, qi)),
-                pl.BlockSpec((1, bk),
-                             lambda b_, h_, ki, qi, lo_, hi_: (b_, ki)),
+                             lambda b_, h_, ki, qi, lo_, hi_: (b_, 0, qi)),
+                pl.BlockSpec((1, 1, bk),
+                             lambda b_, h_, ki, qi, lo_, hi_: (b_, 0, ki)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, bk, d),
@@ -565,6 +578,7 @@ def packed_bwd_pallas(q, k, v, o, lse, do, segment_ids, *, bq: int = 128,
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         ],
-        interpret=interpret,
-    )(lo, hi, q, k, v, do, lse, delta, segment_ids, segment_ids)
+        interpret=resolve_interpret(interpret),
+    )(lo, hi, q, k, v, do, _rows(lse), _rows(delta), _rows(segment_ids),
+      _rows(segment_ids))
     return dq, dk, dv

@@ -23,7 +23,7 @@ from repro.kernels.prefix_attn import kernel as K
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def prefix_flash_attention(q, k, v, cut_lens, window: int = 0,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     o, _ = K.fwd_pallas(q, k, v, cut_lens, window=window, bq=bq, bk=bk,
                         interpret=interpret)
     return o
@@ -52,7 +52,7 @@ prefix_flash_attention.defvjp(_fwd, _bwd)
 
 
 def attention_bthd(q, k, v, cut_lens, *, window: int = 0, bq: int = 128,
-                   bk: int = 128, interpret: bool = True):
+                   bk: int = 128, interpret: bool | None = None):
     """(B, T, H, D)-layout convenience wrapper matching the model's attention
     call sites; transposes around the kernel layout."""
     qt = jnp.swapaxes(q, 1, 2)
@@ -65,7 +65,7 @@ def attention_bthd(q, k, v, cut_lens, *, window: int = 0, bq: int = 128,
 # ------------------------------------------------------- packed (segment-id)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def packed_flash_attention(q, k, v, segment_ids, bq: int = 128,
-                           bk: int = 128, interpret: bool = True):
+                           bk: int = 128, interpret: bool | None = None):
     o, _ = K.packed_fwd_pallas(q, k, v, segment_ids, bq=bq, bk=bk,
                                interpret=interpret)
     return o
@@ -94,7 +94,7 @@ packed_flash_attention.defvjp(_packed_fwd, _packed_bwd)
 
 
 def packed_attention_bthd(q, k, v, segment_ids, *, bq: int = 128,
-                          bk: int = 128, interpret: bool = True):
+                          bk: int = 128, interpret: bool | None = None):
     """(B, T, H, D)-layout convenience wrapper for the packed variant."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)

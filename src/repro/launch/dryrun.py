@@ -19,12 +19,15 @@ Per cell this script:
      for totals directly (see launch/hlo_stats.py),
   4. appends a JSON record to --out (default experiments/dryrun.jsonl).
 """
-# The dry-run (and ONLY the dry-run) needs 512 placeholder devices; these
-# two lines must run before ANY other import — jax locks the device count
-# on first initialization.
+# The dry-run (and ONLY the dry-run) needs 512 placeholder host devices
+# and never needs an accelerator, so it also pins itself to the CPU (a
+# chip belongs to one process; this one must not take it).  These lines
+# must run before ANY other import — jax locks the platform and device
+# count on first initialization.
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse          # noqa: E402
 import dataclasses       # noqa: E402

@@ -100,8 +100,6 @@ def collective_bytes(hlo_text: str, num_devices: int) -> Dict[str, float]:
 
 def cost_stats(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5: one dict per computation
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

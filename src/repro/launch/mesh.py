@@ -10,12 +10,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    try:  # jax >= 0.5: explicit Auto axis types
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    except (AttributeError, TypeError):  # older jax: Auto is the only mode
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None, axes=None):
@@ -48,7 +45,5 @@ def slice_mesh(devices, axis: str = "data"):
 
 
 def set_ambient_mesh(mesh):
-    """jax.set_mesh where available (jax >= 0.6).  On older jax the explicit
-    NamedShardings passed to jit carry the mesh, so this is optional."""
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
+    """Make ``mesh`` the process's ambient mesh (``jax.set_mesh``)."""
+    jax.set_mesh(mesh)

@@ -20,6 +20,7 @@ import sys
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import AdamWConfig
 from repro.rl import NATGRPOTrainer, NATTrainerConfig, RolloutConfig
 from repro.rl.dist_trainer import make_dist_trainer
@@ -96,6 +97,7 @@ def main(argv=None):
                          "PagePoolExhausted before escalating")
     ap.add_argument("--eval-prompts", type=int, default=32)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     model_cfg = build_model_cfg(args.arch, args.preset)
     sel_kwargs = ()

@@ -396,9 +396,12 @@ class AsyncNATGRPOTrainer:
                 "rl.learner.make_train_step(paged=True) with a "
                 "learner_retain paged engine (DESIGN.md §11)")
         self.layout = make_layout(layout_name, **dict(tcfg.layout_kwargs))
+        # the optimizer state is donated: old and new f32 moments never
+        # coexist, which is what lets a one-chip share of an 8B-width model
+        # fit 16 GB.  Params are not: the published snapshot aliases them.
         self._train_step = jax.jit(make_train_step(
             model_cfg, tcfg.grpo, tcfg.adamw, mesh=mesh, rules=rules,
-            vocab_chunks=1, packed=self.layout.packed))
+            vocab_chunks=1, packed=self.layout.packed), donate_argnums=(1,))
         t_max = tcfg.max_prompt_len + tcfg.rollout.max_new_tokens
         self.ladder = bucket_ladder(t_max, tcfg.num_buckets, tcfg.bucket_align)
         self.history: list = []

@@ -216,6 +216,11 @@ class DistNATGRPOTrainer(AsyncNATGRPOTrainer):
         try:
             self._actor_fleet(rep)
         except BaseException as e:
+            if self._stop_evt.is_set():
+                # close() poisoned the queue under a blocked put/reserve:
+                # an orderly shutdown, not a replica death to reclaim (and
+                # retiring would wipe the fleet's watermarks)
+                return
             if self.supervisor is not None:
                 self.supervisor.report_failure(rep.name, e)
             else:

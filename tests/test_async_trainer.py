@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core.grpo import group_advantages
 from repro.core.repack import bucket_ladder, pick_bucket
@@ -246,11 +247,7 @@ def test_sample_queue_cancel_unblocks_gap():
     assert q.inflight() == 0
 
 
-# --- multi-producer property (hypothesis when installed; seeded fallback) ---
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from hypothesis_fallback import given, settings, st
+# --- multi-producer property ---
 
 
 @settings(max_examples=15, deadline=None)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core.grpo import full_token_loss_reference, nat_grpo_loss
 from repro.core.selectors import (
@@ -146,11 +147,6 @@ def test_grpo_special_case_full_tokens(batch):
 
 
 # ---------------------------------------- arbitrary-design property test
-# (hypothesis when installed; deterministic seeded fallback otherwise)
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from hypothesis_fallback import given, settings, st
 
 
 @jax.jit

@@ -6,6 +6,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.models import init_params, model_decl
 from repro.models.config import ModelConfig, dense_blocks
@@ -462,11 +463,6 @@ def test_trainer_paged_rollout_metrics():
 
 
 # ------------------------------------------- allocator property tests
-# (hypothesis when installed; deterministic seeded fallback otherwise)
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from hypothesis_fallback import given, settings, st
 
 
 def _check_partition(a):

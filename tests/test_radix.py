@@ -9,10 +9,7 @@ import pytest
 
 from repro.rl import PageAllocator, RadixPrefixCache
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 PL = 4  # page_len for every trie in this file
 

@@ -1,9 +1,6 @@
 """Bucket ladder / physical repacking properties."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.repack import (
     bucket_ladder, expected_token_savings, pick_bucket, plan_microbatches,

@@ -3,10 +3,7 @@ handling, mesh-absence handling (property-based)."""
 import jax
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover — CI installs hypothesis
-    from hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import (

@@ -27,6 +27,7 @@ import types
 import jax
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.dist import PublicationError, WeightPublisher
 from repro.models.config import ModelConfig, dense_blocks
@@ -45,11 +46,6 @@ from repro.rl import (
     retry_call,
 )
 from repro.testing import FaultPlan, FaultSpec, InjectedActorDeath, InjectedFault
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from hypothesis_fallback import given, settings, st
 
 
 def tiny_cfg(**kw):
