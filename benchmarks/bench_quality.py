@@ -58,7 +58,7 @@ def run(steps: int = 60, seeds=(0, 1, 2), eval_prompts: int = 48) -> dict:
             accs.append(ev["accuracy"])
             rewards.append(np.mean([m["reward_mean"] for m in hist[-10:]]))
             ents.append(np.mean([m["entropy_behavior"] for m in hist[-10:]]))
-            toks.append(np.mean([m["learner_tokens"] for m in hist]))
+            toks.append(np.mean([m["tokens_scored"] for m in hist]))
         dt = time.perf_counter() - t0
         (am, ah), (rm_, rh), (em, eh) = ci95(accs), ci95(rewards), ci95(ents)
         print(f"{name:10s} {am:8.3f}±{ah:<6.3f} {rm_:8.3f}±{rh:<4.3f} "

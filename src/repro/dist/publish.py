@@ -96,7 +96,7 @@ class WeightPublisher:
         Returns ``{name: resharded_tree}``.  ``epoch`` defaults to the
         next integer after the last published epoch.
         """
-        with self._lock:
+        with jax.profiler.TraceAnnotation("nat.publish"), self._lock:
             if epoch is None:
                 epoch = self.stats["epoch"] + 1
             nbytes = tree_bytes(params)

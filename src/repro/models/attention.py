@@ -452,25 +452,30 @@ def paged_decode_attention(
     q = apply_rope(q, posb, rope_theta)
     k = apply_rope(k, posb, rope_theta)
 
-    new_pool = paged_cache_update(pool, k, v, posb, write_page, write_off)
-    scale = 1.0 / jnp.sqrt(dh).astype(F32)
+    # both impl paths under one scope, so a profile names the attention
+    # (and, nested, the pool write) whichever path runs
+    with jax.named_scope("paged_decode_attn"):
+        with jax.named_scope("paged_decode_attn.write"):
+            new_pool = paged_cache_update(pool, k, v, posb, write_page,
+                                          write_off)
+        scale = 1.0 / jnp.sqrt(dh).astype(F32)
 
-    if impl == "kernel":
-        from repro.kernels.paged_attn import paged_attention
+        if impl == "kernel":
+            from repro.kernels.paged_attn import paged_attention
 
-        o = paged_attention(
-            q[:, 0], new_pool["k"], new_pool["v"], new_pool["pos"],
-            block_tables, posb[:, 0])[:, None]
-    else:
-        kg, vg, posg = gather_pages(new_pool, block_tables)
-        valid = (posg >= 0) & (posg <= posb)
-        kf = repeat_kv(kg, h // kvh)
-        vf = repeat_kv(vg, h // kvh)
-        s = jnp.einsum("bthd,bshd->bhts", q, kf.astype(q.dtype),
-                       preferred_element_type=F32) * scale
-        s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-        pa = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhts,bshd->bthd", pa.astype(vf.dtype), vf)
+            o = paged_attention(
+                q[:, 0], new_pool["k"], new_pool["v"], new_pool["pos"],
+                block_tables, posb[:, 0])[:, None]
+        else:
+            kg, vg, posg = gather_pages(new_pool, block_tables)
+            valid = (posg >= 0) & (posg <= posb)
+            kf = repeat_kv(kg, h // kvh)
+            vf = repeat_kv(vg, h // kvh)
+            s = jnp.einsum("bthd,bshd->bhts", q, kf.astype(q.dtype),
+                           preferred_element_type=F32) * scale
+            s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+            pa = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhts,bshd->bthd", pa.astype(vf.dtype), vf)
     out = jnp.einsum("bthk,hkd->btd", o, p["wo"])
     return out, new_pool
 

@@ -66,6 +66,8 @@ from repro.rl.rollout import (
     rollout_group_continuous,
 )
 
+span = jax.profiler.TraceAnnotation    # host span in the profiler's trace
+
 
 @dataclasses.dataclass(frozen=True)
 class NATTrainerConfig:
@@ -521,20 +523,21 @@ class AsyncNATGRPOTrainer:
         self.pipeline.step = i + 1  # keep the checkpoint cursor honest
         key0 = self._actor_key
         self._actor_key, k_roll, k_sel = jax.random.split(self._actor_key, 3)
-        t0 = time.perf_counter()
-        if self.engine is not None:
-            rb = rollout_group_continuous(
-                params, self.model_cfg, tcfg.rollout,
-                pb.tokens, pb.prompt_lens, k_roll, engine=self.engine,
-                budgets=self._budgets_for(i))
-        else:
-            rb = rollout_group(params, self.model_cfg, tcfg.rollout,
-                               pb.tokens, pb.prompt_lens, k_roll)
+        with span("nat.rollout"):
+            t0 = time.perf_counter()
+            if self.engine is not None:
+                rb = rollout_group_continuous(
+                    params, self.model_cfg, tcfg.rollout,
+                    pb.tokens, pb.prompt_lens, k_roll, engine=self.engine,
+                    budgets=self._budgets_for(i))
+            else:
+                rb = rollout_group(params, self.model_cfg, tcfg.rollout,
+                                   pb.tokens, pb.prompt_lens, k_roll)
+            t_rollout = time.perf_counter() - t0
         self._next_group = i + 1
         return TaggedGroup(
             index=i, behavior_version=version, batch=rb,
-            prompt_batch=pb, key_sel=k_sel,
-            t_rollout=time.perf_counter() - t0, key0=key0)
+            prompt_batch=pb, key_sel=k_sel, t_rollout=t_rollout, key0=key0)
 
     def _actor_pergroup(self) -> None:
         """Per-group rollouts from a pipelined thread: the overlap path for
@@ -627,6 +630,8 @@ class AsyncNATGRPOTrainer:
                 "slot_substeps": (cur["slot_substeps"]
                                   - gs.stats0["slot_substeps"]),
                 "refills": cur["refills"] - gs.stats0["refills"],
+                "sync_s": cur["sync_s"] - gs.stats0["sync_s"],
+                "host_s": cur["host_s"] - gs.stats0["host_s"],
             }
             rb = batch_from_completions(
                 comps, gs.pb.tokens, gs.pb.prompt_lens, self.tcfg.rollout,
@@ -665,74 +670,95 @@ class AsyncNATGRPOTrainer:
             self._cv.notify_all()
 
     def train_step(self) -> dict:
+        """One learner step; traced as the profiler span ``nat.train_step``
+        holding ``nat.rollout``, ``nat.select``, ``nat.layout`` and
+        ``nat.learn`` (``nat.learn.dispatch``, ``nat.learn.sync``,
+        ``nat.publish``), the last covering ``time_learn``'s interval."""
+        with span("nat.train_step"):
+            return self._step()
+
+    def _step(self) -> dict:
         self._ensure_actor()
         t0 = time.perf_counter()
         tcfg = self.tcfg
+        # generous pop timeout: surfaces a wedged actor as an error instead
+        # of a hung CI job (actor errors propagate via SampleQueue.fail)
         if tcfg.max_staleness == 0 and self.queue.qsize() == 0:
             # serial path: produce inline, no actor thread exists (the gate
             # would only ever let it roll while this call waited anyway)
             with self._cv:
                 params, version = self._published
             self.queue.put(self._roll_next_group(params, version))
-        # generous timeout: surfaces a wedged actor as an error instead of a
-        # hung CI job (actor errors propagate via SampleQueue.fail)
-        tg = self.queue.pop(self._learner_version, timeout=600.0)
+            tg = self.queue.pop(self._learner_version, timeout=600.0)
+        else:
+            with span("nat.rollout"):    # the wait for the actor's group
+                tg = self.queue.pop(self._learner_version, timeout=600.0)
         rb, pb = tg.batch, tg.prompt_batch
         staleness = self._learner_version - tg.behavior_version
         t_roll = time.perf_counter()
 
-        # rewards on FULL responses (never affected by token selection)
-        p, g = tcfg.prompts_per_step, tcfg.rollout.group_size
-        rewards = np.zeros((p, g), np.float32)
-        for i in range(p):
-            for j in range(g):
-                r = i * g + j
-                pl, rl = int(rb.prompt_lens[r]), int(rb.response_lens[r])
-                resp = rb.tokens[r, pl:pl + rl]
-                rewards[i, j] = self.env.reward(pb.prompts[i], resp)
-        adv = np.asarray(group_advantages(jnp.asarray(rewards),
-                                          tcfg.grpo.adv_eps)).reshape(-1)
+        with span("nat.select"):
+            # rewards on FULL responses (never affected by token selection)
+            p, g = tcfg.prompts_per_step, tcfg.rollout.group_size
+            rewards = np.zeros((p, g), np.float32)
+            for i in range(p):
+                for j in range(g):
+                    r = i * g + j
+                    pl, rl = int(rb.prompt_lens[r]), int(rb.response_lens[r])
+                    resp = rb.tokens[r, pl:pl + rl]
+                    rewards[i, j] = self.env.reward(pb.prompts[i], resp)
+            adv = np.asarray(group_advantages(
+                jnp.asarray(rewards), tcfg.grpo.adv_eps)).reshape(-1)
 
-        # NAT selection
-        rmask = jnp.asarray(rb.response_mask)
-        if isinstance(self.selector, EntropySelector):
-            sel = self.selector(tg.key_sel, rmask, jnp.asarray(rb.entropies))
-        else:
-            sel = self.selector(tg.key_sel, rmask)
-        ht_w = np.asarray(sel.ht_weights, np.float32)
-        keep_len = np.asarray(sel.keep_len)
+            # NAT selection
+            rmask = jnp.asarray(rb.response_mask)
+            if isinstance(self.selector, EntropySelector):
+                sel = self.selector(tg.key_sel, rmask,
+                                    jnp.asarray(rb.entropies))
+            else:
+                sel = self.selector(tg.key_sel, rmask)
+            ht_w = np.asarray(sel.ht_weights, np.float32)
+            keep_len = np.asarray(sel.keep_len)
 
-        batch = {
-            "tokens": rb.tokens,
-            "response_mask": rb.response_mask,
-            "old_logp": rb.old_logp,
-            "advantages": adv.astype(np.float32),
-            "ht_weights": ht_w,
-            "orig_lengths": rb.response_lens.astype(np.float32),
-            "lengths": (rb.prompt_lens + rb.response_lens).astype(np.int32),
-            # staleness-corrected HT objective (DESIGN.md §6): the engine's
-            # in-flight logprobs are the behaviour policy; rows that lag the
-            # learner version get the truncated-IS correction in the loss
-            "behavior_logp": rb.old_logp,
-            "staleness": np.full((rb.tokens.shape[0],), staleness, np.float32),
-        }
+            batch = {
+                "tokens": rb.tokens,
+                "response_mask": rb.response_mask,
+                "old_logp": rb.old_logp,
+                "advantages": adv.astype(np.float32),
+                "ht_weights": ht_w,
+                "orig_lengths": rb.response_lens.astype(np.float32),
+                "lengths": (rb.prompt_lens
+                            + rb.response_lens).astype(np.int32),
+                # staleness-corrected HT objective (DESIGN.md §6): the
+                # engine's in-flight logprobs are the behaviour policy; rows
+                # that lag the learner version get the truncated-IS
+                # correction in the loss
+                "behavior_logp": rb.old_logp,
+                "staleness": np.full((rb.tokens.shape[0],), staleness,
+                                     np.float32),
+            }
 
         # batch layout (core/layout.py): bucketed slicing, hull packing, or
         # the raw padded grid — the selection above is layout-invariant
-        lb = self.layout.build(
-            batch, prompt_lens=rb.prompt_lens,
-            response_lens=rb.response_lens, keep_len=keep_len,
-            keep_mask=ht_w > 0, prefix_structured=sel.prefix_structured,
-            ladder=self.ladder)
+        with span("nat.layout"):
+            lb = self.layout.build(
+                batch, prompt_lens=rb.prompt_lens,
+                response_lens=rb.response_lens, keep_len=keep_len,
+                keep_mask=ht_w > 0, prefix_structured=sel.prefix_structured,
+                ladder=self.ladder)
         batch = lb.data
         t_sel = time.perf_counter()
 
-        self.params, self.opt_state, metrics = self._train_step(
-            self.params, self.opt_state, {k: jnp.asarray(v)
-                                          for k, v in batch.items()})
-        metrics = {k: float(v) for k, v in metrics.items()}
-        self._publish()
-        t_end = time.perf_counter()
+        with span("nat.learn"):
+            with span("nat.learn.dispatch"):
+                self.params, self.opt_state, metrics = self._train_step(
+                    self.params, self.opt_state,
+                    {k: jnp.asarray(v) for k, v in batch.items()})
+            with span("nat.learn.sync"):
+                metrics = {k: float(v) for k, v in metrics.items()}
+            with span("nat.publish"):
+                self._publish()
+            t_end = time.perf_counter()
 
         rstats = rb.stats or {}
         metrics.update(
@@ -740,8 +766,6 @@ class AsyncNATGRPOTrainer:
             reward_max=float(rewards.max(axis=1).mean()),
             completed_frac=float(rb.completed.mean()),
             resp_len_mean=float(rb.response_lens.mean()),
-            # legacy alias of tokens_scored (pre-layout consumers)
-            learner_tokens=lb.tokens_scored,
             bucket_len=lb.row_len,
             # layout accounting (DESIGN.md §7): tokens the update physically
             # scored and the kept-budget fraction of them — the learner-side
@@ -754,6 +778,11 @@ class AsyncNATGRPOTrainer:
             tokens_generated=int(rstats.get("tokens_generated", 0)),
             tokens_budget=int(rstats.get("tokens_budget", 0)),
             rollout_decode_steps=int(rstats.get("decode_steps", 0)),
+            # engine rounds and their host split (rl/engine.py): seconds
+            # blocked reading the control planes, and host seconds after
+            rollout_rounds=int(rstats.get("rounds", 0)),
+            rollout_sync_s=float(rstats.get("sync_s", 0.0)),
+            rollout_host_s=float(rstats.get("host_s", 0.0)),
             rollout_cancelled=int(rstats.get("cancelled", 0)),
             rollout_utilization=(
                 rstats.get("tokens_generated", 0)
@@ -765,7 +794,6 @@ class AsyncNATGRPOTrainer:
             policy_version=self._learner_version,
             behavior_version=tg.behavior_version,
             staleness=staleness,
-            queue_depth=self.queue.qsize(),
             dropped_stale=self.queue.dropped_stale,
             time_rollout=tg.t_rollout,
             time_wait=t_roll - t0,

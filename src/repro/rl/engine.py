@@ -39,11 +39,19 @@ step.  ``run`` is the run-to-completion wrapper over the same rounds; the
 stream-overlapped trainer (``rl/async_trainer.py``) drives sessions
 directly so rollouts from one policy version keep draining while the
 learner steps the next.
+
+Each round is traced as a ``nat.engine.round`` profiler span holding
+``nat.engine.sync`` (the blocking read of the control planes: the wait for
+the device), ``nat.engine.harvest``, ``nat.engine.place`` and
+``nat.engine.dispatch``; the jitted step's parts carry ``engine.*`` name
+scopes.  ``stats["sync_s"]`` and ``stats["host_s"]`` count the seconds of
+the round spent in the sync and after it, always on.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 from typing import Callable, Iterable, Optional, Sequence
 
 import jax
@@ -67,6 +75,7 @@ from repro.rl.radix import RadixPrefixCache
 
 Array = jax.Array
 F32 = jnp.float32
+span = jax.profiler.TraceAnnotation    # host span in the profiler's trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -270,14 +279,16 @@ class ContinuousRolloutEngine:
         n = rcfg.max_new_tokens
         cache_len = self.cache_len
 
-        def step(params, state, refill_toks, refill_lens, refill_budgets,
-                 refill_slots, refill_mask, cancel_mask):
+        def engine_step(params, state, refill_toks, refill_lens,
+                        refill_budgets, refill_slots, refill_mask,
+                        cancel_mask):
             # refill_* are (R,) lanes; refill_slots names each lane's target
             # arena slot; masked-out lanes scatter nowhere (index S, dropped).
             st = dict(state)
             # 1. cancelled slots become free (harvest already happened on host)
-            st["active"] = st["active"] & ~cancel_mask
-            st["done"] = st["done"] & ~cancel_mask
+            with jax.named_scope("engine.invalidate"):
+                st["active"] = st["active"] & ~cancel_mask
+                st["done"] = st["done"] & ~cancel_mask
 
             # 2. refill: R-wide prefill scattered into the arena at the
             # target slots.  lax.cond skips it on pure-decode rounds, and
@@ -318,23 +329,28 @@ class ContinuousRolloutEngine:
                     jnp.zeros((r, n), F32), mode="drop")
                 return st
 
-            st = jax.lax.cond(refill_mask.any(), do_refill, lambda s: dict(s), st)
+            with jax.named_scope("engine.prefill"):
+                st = jax.lax.cond(refill_mask.any(), do_refill,
+                                  lambda s: dict(s), st)
 
             # 3. masked decode substeps: retired/empty slots ride along (the
             # shapes are static) but emit nothing and hold their state.
             def substep(st, _):
                 st = dict(st)
-                nxt, live = _substep_sample(st, rcfg, n, s_slots)
+                with jax.named_scope("engine.sample"):
+                    nxt, live = _substep_sample(st, rcfg, n, s_slots)
                 new_logits, new_cache = decode_step(
                     params, cfg, nxt, st["cache"], st["pos"])
                 st["cache"] = new_cache
                 st = _substep_advance(st, nxt, live, new_logits, rcfg)
                 return st, None
 
-            st, _ = jax.lax.scan(substep, st, None, length=ecfg.steps_per_sync)
+            with jax.named_scope("engine.decode"):
+                st, _ = jax.lax.scan(substep, st, None,
+                                     length=ecfg.steps_per_sync)
             return st
 
-        return step
+        return engine_step
 
     # ----------------------------------------------------- host side: session
     def begin(
@@ -374,7 +390,7 @@ class ContinuousRolloutEngine:
         self._state = state
         self.stats = {"rounds": 0, "decode_steps": 0, "refills": 0,
                       "tokens_generated": 0, "cancelled": 0,
-                      "slot_substeps": 0}
+                      "slot_substeps": 0, "sync_s": 0.0, "host_s": 0.0}
 
     def _validate_requests(self, requests: Sequence[Request]) -> None:
         rcfg, tp = self.rcfg, self.ecfg.max_prompt_len
@@ -444,8 +460,23 @@ class ContinuousRolloutEngine:
     def _collect_retirements(self) -> tuple:
         """Sync the control planes and harvest every retired or cancelled
         slot.  Returns (harvested Completions, device cancel_mask (S,)) —
-        the round head shared by the dense and paged drive loops."""
-        state, slot_uid = self._state, self._slot_uid
+        the round head shared by the dense and paged drive loops.  The
+        sync is the round's wait for the device (``stats["sync_s"]``);
+        the round's host time counts from its return."""
+        state = self._state
+        with span("nat.engine.sync"):
+            t0 = time.perf_counter()
+            active = np.asarray(state["active"])
+            done = np.asarray(state["done"])
+            self._t_synced = time.perf_counter()
+        self.stats["sync_s"] += self._t_synced - t0
+        with span("nat.engine.harvest"):
+            return self._harvest_round(state, active, done)
+
+    def _harvest_round(self, state, active, done) -> tuple:
+        """``_collect_retirements`` after the sync: stream, fetch and
+        harvest, given the synced ``active`` / ``done`` planes."""
+        slot_uid = self._slot_uid
         to_cancel = self._to_cancel
         s_slots = self.ecfg.num_slots
         harvested: list = []
@@ -466,9 +497,7 @@ class ContinuousRolloutEngine:
                         out_tok_h[s, self._streamed[s]:k].copy())
                     self._streamed[s] = k
 
-        # -- sync the two control planes; fetch buffers only on retirement
-        active = np.asarray(state["active"])
-        done = np.asarray(state["done"])
+        # -- fetch buffers only on retirement
         retired = [s for s in range(s_slots)
                    if slot_uid[s] is not None and active[s] and done[s]]
         cancel_mask = np.zeros((s_slots,), bool)
@@ -514,6 +543,13 @@ class ContinuousRolloutEngine:
         free slots from the queue, dispatch the jitted step.  Returns the
         Completions retired this round (possibly empty).  When the session
         is idle the call is a no-op."""
+        with span("nat.engine.round"):
+            harvested = self._round()
+            self.stats["host_s"] += time.perf_counter() - self._t_synced
+        return harvested
+
+    def _round(self) -> list:
+        """The round itself; ``drive`` holds its span and its counters."""
         if self.chaos is not None:
             self.chaos.fire("drive", replica=self.chaos_replica,
                             index=self.stats.get("rounds", 0))
@@ -525,40 +561,43 @@ class ContinuousRolloutEngine:
 
         # -- refill free slots from the queue (skipping cancelled uids),
         # at most R lanes per round
-        lanes = ecfg.lanes
-        refill_mask = np.zeros((lanes,), bool)
-        refill_toks = np.full((lanes, tp), rcfg.pad_id, np.int32)
-        refill_lens = np.ones((lanes,), np.int32)
-        refill_budgets = np.zeros((lanes,), np.int32)
-        refill_slots = np.zeros((lanes,), np.int32)
-        lane = 0
-        for s in range(s_slots):
-            if slot_uid[s] is not None or lane >= lanes:
-                continue
-            while queue and queue[0].uid in to_cancel:
-                harvested.append(self._cancelled_completion(queue.popleft()))
-            if not queue:
-                break
-            r = queue.popleft()
-            pl = len(r.tokens)
-            refill_toks[lane, :pl] = r.tokens
-            refill_lens[lane] = pl
-            refill_budgets[lane] = r.budget or rcfg.max_new_tokens
-            refill_slots[lane] = s
-            refill_mask[lane] = True
-            slot_uid[s] = r.uid
-            self._streamed[s] = 0
-            lane += 1
+        with span("nat.engine.place"):
+            lanes = ecfg.lanes
+            refill_mask = np.zeros((lanes,), bool)
+            refill_toks = np.full((lanes, tp), rcfg.pad_id, np.int32)
+            refill_lens = np.ones((lanes,), np.int32)
+            refill_budgets = np.zeros((lanes,), np.int32)
+            refill_slots = np.zeros((lanes,), np.int32)
+            lane = 0
+            for s in range(s_slots):
+                if slot_uid[s] is not None or lane >= lanes:
+                    continue
+                while queue and queue[0].uid in to_cancel:
+                    harvested.append(
+                        self._cancelled_completion(queue.popleft()))
+                if not queue:
+                    break
+                r = queue.popleft()
+                pl = len(r.tokens)
+                refill_toks[lane, :pl] = r.tokens
+                refill_lens[lane] = pl
+                refill_budgets[lane] = r.budget or rcfg.max_new_tokens
+                refill_slots[lane] = s
+                refill_mask[lane] = True
+                slot_uid[s] = r.uid
+                self._streamed[s] = 0
+                lane += 1
 
         if not refill_mask.any() and all(u is None for u in slot_uid):
             self.last_state = state  # session quiescent: expose for tests
             return harvested
 
-        self._state = self._step(
-            self._params, state, jnp.asarray(refill_toks),
-            jnp.asarray(refill_lens), jnp.asarray(refill_budgets),
-            jnp.asarray(refill_slots), jnp.asarray(refill_mask),
-            jnp.asarray(cancel_mask))
+        with span("nat.engine.dispatch"):
+            self._state = self._step(
+                self._params, state, jnp.asarray(refill_toks),
+                jnp.asarray(refill_lens), jnp.asarray(refill_budgets),
+                jnp.asarray(refill_slots), jnp.asarray(refill_mask),
+                jnp.asarray(cancel_mask))
         self.stats["rounds"] += 1
         self.stats["decode_steps"] += ecfg.steps_per_sync
         self.stats["slot_substeps"] += ecfg.steps_per_sync * s_slots
@@ -1056,19 +1095,23 @@ class PagedRolloutEngine(ContinuousRolloutEngine):
         assert not (external_prefill and use_prefix), \
             "prefix_cache cannot span the prefill/decode split"
 
-        def step(params, state, block_tables, free_page_mask, refill_toks,
-                 refill_lens, refill_prefix_len, refill_prefix_bt,
-                 refill_page_ids, refill_slots, refill_budgets,
-                 refill_mask, resume_slots, resume_logits, resume_lens,
-                 resume_budgets, resume_mask, cancel_mask, *handoff):
+        def paged_engine_step(params, state, block_tables, free_page_mask,
+                              refill_toks, refill_lens, refill_prefix_len,
+                              refill_prefix_bt, refill_page_ids,
+                              refill_slots, refill_budgets, refill_mask,
+                              resume_slots, resume_logits, resume_lens,
+                              resume_budgets, resume_mask, cancel_mask,
+                              *handoff):
             st = dict(state)
-            # 1. cancelled slots become free (harvest happened on host)
-            st["active"] = st["active"] & ~cancel_mask
-            st["done"] = st["done"] & ~cancel_mask
-            # 2. pos-poison freed pages before any reuse this round: a
-            # recycled page must never leak its previous occupant's
-            # positions as valid entries (gather isolation)
-            st["cache"] = invalidate_pages(cfg, st["cache"], free_page_mask)
+            with jax.named_scope("engine.invalidate"):
+                # 1. cancelled slots become free (harvest happened on host)
+                st["active"] = st["active"] & ~cancel_mask
+                st["done"] = st["done"] & ~cancel_mask
+                # 2. pos-poison freed pages before any reuse this round: a
+                # recycled page must never leak its previous occupant's
+                # positions as valid entries (gather isolation)
+                st["cache"] = invalidate_pages(cfg, st["cache"],
+                                               free_page_mask)
 
             # 3. group refill: one prompt prefill per lane, its raw KV
             # scattered into the shared prompt pages, logits and per-slot
@@ -1165,8 +1208,9 @@ class PagedRolloutEngine(ContinuousRolloutEngine):
                     refill_budgets.reshape(-1),
                     jnp.repeat(logits0, gmax, axis=0), n, rcfg.pad_id)
 
-            st = jax.lax.cond(refill_mask.any(), do_refill,
-                              lambda s_: dict(s_), st)
+            with jax.named_scope("engine.prefill"):
+                st = jax.lax.cond(refill_mask.any(), do_refill,
+                                  lambda s_: dict(s_), st)
 
             # 3b. resume parked siblings (pure-attention configs): the
             # prompt state is exactly its shared pages (already in the
@@ -1180,13 +1224,15 @@ class PagedRolloutEngine(ContinuousRolloutEngine):
                                           resume_budgets, resume_logits, n,
                                           rcfg.pad_id)
 
-            st = jax.lax.cond(resume_mask.any(), do_resume,
-                              lambda s_: dict(s_), st)
+            with jax.named_scope("engine.resume"):
+                st = jax.lax.cond(resume_mask.any(), do_resume,
+                                  lambda s_: dict(s_), st)
 
             # 4. masked decode substeps through the block tables
             def substep(st, _):
                 st = dict(st)
-                nxt, live = _substep_sample(st, rcfg, n, s_slots)
+                with jax.named_scope("engine.sample"):
+                    nxt, live = _substep_sample(st, rcfg, n, s_slots)
                 # write target: decode token i = n_gen opens/extends the
                 # slot's private pages AFTER its prompt pages — never a
                 # shared page, so prompt pages stay read-only
@@ -1206,11 +1252,12 @@ class PagedRolloutEngine(ContinuousRolloutEngine):
                 st = _substep_advance(st, nxt, live, new_logits, rcfg)
                 return st, None
 
-            st, _ = jax.lax.scan(substep, st, None,
-                                 length=ecfg.steps_per_sync)
+            with jax.named_scope("engine.decode"):
+                st, _ = jax.lax.scan(substep, st, None,
+                                     length=ecfg.steps_per_sync)
             return st
 
-        return step
+        return paged_engine_step
 
     # ------------------------------------------------------------- drive
     def _dispatch(self, state, bt, free_mask, refill_toks, refill_lens,
@@ -1240,7 +1287,7 @@ class PagedRolloutEngine(ContinuousRolloutEngine):
         """Queued groups plus partially-placed (parked) groups."""
         return len(self._queue) + len(self._pending)
 
-    def drive(self) -> list:
+    def _round(self) -> list:
         """One paged round: harvest (freeing pages), resume parked
         siblings into freed slots, place queued groups with one shared
         prompt prefill each, allocate-ahead decode pages, dispatch the
@@ -1257,213 +1304,217 @@ class PagedRolloutEngine(ContinuousRolloutEngine):
         state, slot_uid, queue = self._state, self._slot_uid, self._queue
         harvested, cancel_mask = self._collect_retirements()
 
-        if self._prefix_cache is not None:
-            # nodes inserted last round are matchable now (their prefill
-            # retired with the previous step), and stale-epoch branches
-            # whose readers drained get collected
-            self._prefix_cache.step()
-            self._dirty.update(self._prefix_cache.reap())
-
-        # snapshot prompt logits for parked groups (written by the prefill
-        # one round earlier; read before any new prefill reuses the lane)
-        if any(rec["logits"] is None for rec in self._pending):
-            lane_logits = np.asarray(state["prefill_logits"])
-            for rec in self._pending:
-                if rec["logits"] is None:
-                    rec["logits"] = lane_logits[rec["lane"]].copy()
-
-        # -- allocate-ahead for slots already decoding: each must own
-        # pages for every token it can write this round (exhaustion here
-        # is a real undersized pool — raise, never corrupt)
-        occupied = [s for s in range(s_slots) if slot_uid[s] is not None]
-        for s in occupied:
-            want = int(min(self._n_gen_ub[s] + sps, self._slot_budget[s]))
-            need = -(-want // pl_)
-            short = need - len(self._slot_decode_pages[s])
-            if short > 0:
-                self._ensure_free(short)  # evict cold branches, else raise
-            while len(self._slot_decode_pages[s]) < need:
-                self._slot_decode_pages[s].extend(
-                    self._alloc.alloc(1, f" (slot {s} decode-ahead)"))
-        free_slots = [s for s in range(s_slots) if slot_uid[s] is None]
-
-        def place(s: int, r: Request, plen: int, ppages: list,
-                  first_ref: bool) -> int:
-            """Install sibling ``r`` in slot ``s``: take a prompt-page
-            reference (unless it inherits the allocation's first ref) and
-            allocate its first decode pages."""
-            budget = r.budget or rcfg.max_new_tokens
-            if not first_ref:
-                self._alloc.retain(ppages)
-            slot_uid[s] = r.uid
-            self._streamed[s] = 0
-            self._slot_prompt_pages[s] = ppages
-            self._slot_decode_pages[s] = self._alloc.alloc(
-                -(-min(sps, budget) // pl_), f" (slot {s} decode)")
-            self._slot_plen[s] = plen
-            self._slot_budget[s] = budget
-            self._n_gen_ub[s] = 0
-            occupied.append(s)
-            return budget
-
-        # -- resume parked siblings into freed slots (pure scatter: their
-        # prompt state is the shared pages + the saved prompt logits);
-        # lane width bounds the (lanes, vocab) logits operand per round —
-        # leftovers simply wait for the next round
-        rw = ecfg.resumes
-        resume_mask = np.zeros((rw,), bool)
-        resume_slots = np.full((rw,), s_slots, np.int32)
-        resume_logits = np.zeros((rw, self.cfg.vocab_size), np.float32)
-        resume_lens = np.ones((rw,), np.int32)
-        resume_budgets = np.zeros((rw,), np.int32)
-        ri = 0
-        for rec in list(self._pending):
-            still = []
-            for r in rec["reqs"]:
-                if r.uid in self._to_cancel:
-                    harvested.append(self._cancelled_completion(r))
-                else:
-                    still.append(r)
-            rec["reqs"] = still
-            while (still and free_slots and ri < rw
-                   and rec["logits"] is not None):
-                budget = still[0].budget or rcfg.max_new_tokens
-                if not self._ensure_free(-(-min(sps, budget) // pl_)):
-                    if not occupied and not resume_mask.any():
-                        self._alloc.alloc(  # raises with occupancy
-                            -(-min(sps, budget) // pl_), " (sibling resume)")
-                    break
-                r = still.pop(0)
-                s = free_slots.pop(0)
-                resume_budgets[ri] = place(s, r, rec["plen"], rec["ppages"],
-                                           first_ref=False)
-                resume_mask[ri] = True
-                resume_slots[ri] = s
-                resume_logits[ri] = rec["logits"]
-                resume_lens[ri] = rec["plen"]
-                ri += 1
-            if not rec["reqs"]:
-                # last sibling placed/cancelled: drop the record's ref
-                self._dirty.update(self._alloc.release(rec["ppages"]))
-                self._pending.remove(rec)
-
-        # -- place queued groups, one prompt prefill per lane; siblings
-        # beyond the free slots are parked (pure-attention) or the whole
-        # group waits (per-slot-state mixers place atomically)
-        lanes, gmax, n_pp = ecfg.group_lanes, ecfg.max_group, self._n_pp
-        refill_mask = np.zeros((lanes,), bool)
-        refill_toks = np.full((lanes, tp), rcfg.pad_id, np.int32)
-        refill_lens = np.ones((lanes,), np.int32)
-        refill_prefix_len = np.zeros((lanes,), np.int32)
-        refill_prefix_bt = np.full((lanes, n_pp), -1, np.int32)
-        refill_page_ids = np.full((lanes, n_pp), self.num_pages, np.int32)
-        refill_slots = np.full((lanes, gmax), s_slots, np.int32)
-        refill_budgets = np.zeros((lanes, gmax), np.int32)
-        lane = 0
-        while lane < lanes and queue and free_slots:
-            group = queue[0]
-            live = []
-            for r in group:
-                if r.uid in self._to_cancel:
-                    harvested.append(self._cancelled_completion(r))
-                else:
-                    live.append(r)
-            # strip emitted cancellations from the QUEUED group in place:
-            # the defer breaks below leave the group at the queue head, and
-            # a re-examined sibling must never re-emit its Completion
-            group[:] = live
-            if not live:
-                queue.popleft()
-                continue
-            if not self._pure_pool and len(live) > len(free_slots):
-                break  # atomic placement: wait for slots to free up
-            placed = live[:len(free_slots)]
-            parked = live[len(placed):]
-            toks0 = np.asarray(live[0].tokens)
-            plen = len(toks0)
-            n_pp_g = -(-plen // pl_)
-            # radix longest-prefix match: matched pages join the group's
-            # block tables read-only; only the suffix prefills.  A fully
-            # cached prompt drops its last matched page so >= 1 token is
-            # always recomputed — the prefill's last-token logits seed
-            # sampling (vLLM-style last-block recompute).
-            m_nodes: list = []
+        with span("nat.engine.place"):
             if self._prefix_cache is not None:
-                m_nodes = self._prefix_cache.lookup(toks0)
-                if m_nodes and len(m_nodes) * pl_ >= plen:
-                    m_nodes = m_nodes[:-1]
-            m_pages = [nd.page for nd in m_nodes]
-            mlen = len(m_pages) * pl_
-            n_fresh = n_pp_g - len(m_pages)
-            need = n_fresh + sum(
-                -(-min(sps, r.budget or rcfg.max_new_tokens) // pl_)
-                for r in placed)
-            if m_pages:
-                # pin the match before eviction can consider those pages
-                self._alloc.retain(m_pages)
-                self._prefix_cache.touch(m_nodes)
-            if not self._ensure_free(need):
+                # nodes inserted last round are matchable now (their prefill
+                # retired with the previous step), and stale-epoch branches
+                # whose readers drained get collected
+                self._prefix_cache.step()
+                self._dirty.update(self._prefix_cache.reap())
+
+            # snapshot prompt logits for parked groups (written by the prefill
+            # one round earlier; read before any new prefill reuses the lane)
+            if any(rec["logits"] is None for rec in self._pending):
+                lane_logits = np.asarray(state["prefill_logits"])
+                for rec in self._pending:
+                    if rec["logits"] is None:
+                        rec["logits"] = lane_logits[rec["lane"]].copy()
+
+            # -- allocate-ahead for slots already decoding: each must own
+            # pages for every token it can write this round (exhaustion here
+            # is a real undersized pool — raise, never corrupt)
+            occupied = [s for s in range(s_slots) if slot_uid[s] is not None]
+            for s in occupied:
+                want = int(min(self._n_gen_ub[s] + sps, self._slot_budget[s]))
+                need = -(-want // pl_)
+                short = need - len(self._slot_decode_pages[s])
+                if short > 0:
+                    self._ensure_free(short)  # evict cold branches, else raise
+                while len(self._slot_decode_pages[s]) < need:
+                    self._slot_decode_pages[s].extend(
+                        self._alloc.alloc(1, f" (slot {s} decode-ahead)"))
+            free_slots = [s for s in range(s_slots) if slot_uid[s] is None]
+
+            def place(s: int, r: Request, plen: int, ppages: list,
+                      first_ref: bool) -> int:
+                """Install sibling ``r`` in slot ``s``: take a prompt-page
+                reference (unless it inherits the allocation's first ref) and
+                allocate its first decode pages."""
+                budget = r.budget or rcfg.max_new_tokens
+                if not first_ref:
+                    self._alloc.retain(ppages)
+                slot_uid[s] = r.uid
+                self._streamed[s] = 0
+                self._slot_prompt_pages[s] = ppages
+                self._slot_decode_pages[s] = self._alloc.alloc(
+                    -(-min(sps, budget) // pl_), f" (slot {s} decode)")
+                self._slot_plen[s] = plen
+                self._slot_budget[s] = budget
+                self._n_gen_ub[s] = 0
+                occupied.append(s)
+                return budget
+
+            # -- resume parked siblings into freed slots (pure scatter: their
+            # prompt state is the shared pages + the saved prompt logits);
+            # lane width bounds the (lanes, vocab) logits operand per round —
+            # leftovers simply wait for the next round
+            rw = ecfg.resumes
+            resume_mask = np.zeros((rw,), bool)
+            resume_slots = np.full((rw,), s_slots, np.int32)
+            resume_logits = np.zeros((rw, self.cfg.vocab_size), np.float32)
+            resume_lens = np.ones((rw,), np.int32)
+            resume_budgets = np.zeros((rw,), np.int32)
+            ri = 0
+            for rec in list(self._pending):
+                still = []
+                for r in rec["reqs"]:
+                    if r.uid in self._to_cancel:
+                        harvested.append(self._cancelled_completion(r))
+                    else:
+                        still.append(r)
+                rec["reqs"] = still
+                while (still and free_slots and ri < rw
+                       and rec["logits"] is not None):
+                    budget = still[0].budget or rcfg.max_new_tokens
+                    if not self._ensure_free(-(-min(sps, budget) // pl_)):
+                        if not occupied and not resume_mask.any():
+                            self._alloc.alloc(  # raises with occupancy
+                                -(-min(sps, budget) // pl_),
+                                " (sibling resume)")
+                        break
+                    r = still.pop(0)
+                    s = free_slots.pop(0)
+                    resume_budgets[ri] = place(s, r, rec["plen"],
+                                               rec["ppages"], first_ref=False)
+                    resume_mask[ri] = True
+                    resume_slots[ri] = s
+                    resume_logits[ri] = rec["logits"]
+                    resume_lens[ri] = rec["plen"]
+                    ri += 1
+                if not rec["reqs"]:
+                    # last sibling placed/cancelled: drop the record's ref
+                    self._dirty.update(self._alloc.release(rec["ppages"]))
+                    self._pending.remove(rec)
+
+            # -- place queued groups, one prompt prefill per lane; siblings
+            # beyond the free slots are parked (pure-attention) or the whole
+            # group waits (per-slot-state mixers place atomically)
+            lanes, gmax, n_pp = ecfg.group_lanes, ecfg.max_group, self._n_pp
+            refill_mask = np.zeros((lanes,), bool)
+            refill_toks = np.full((lanes, tp), rcfg.pad_id, np.int32)
+            refill_lens = np.ones((lanes,), np.int32)
+            refill_prefix_len = np.zeros((lanes,), np.int32)
+            refill_prefix_bt = np.full((lanes, n_pp), -1, np.int32)
+            refill_page_ids = np.full((lanes, n_pp), self.num_pages, np.int32)
+            refill_slots = np.full((lanes, gmax), s_slots, np.int32)
+            refill_budgets = np.zeros((lanes, gmax), np.int32)
+            lane = 0
+            while lane < lanes and queue and free_slots:
+                group = queue[0]
+                live = []
+                for r in group:
+                    if r.uid in self._to_cancel:
+                        harvested.append(self._cancelled_completion(r))
+                    else:
+                        live.append(r)
+                # strip emitted cancellations from the QUEUED group in place:
+                # the defer breaks below leave the group at the queue head, and
+                # a re-examined sibling must never re-emit its Completion
+                group[:] = live
+                if not live:
+                    queue.popleft()
+                    continue
+                if not self._pure_pool and len(live) > len(free_slots):
+                    break  # atomic placement: wait for slots to free up
+                placed = live[:len(free_slots)]
+                parked = live[len(placed):]
+                toks0 = np.asarray(live[0].tokens)
+                plen = len(toks0)
+                n_pp_g = -(-plen // pl_)
+                # radix longest-prefix match: matched pages join the group's
+                # block tables read-only; only the suffix prefills.  A fully
+                # cached prompt drops its last matched page so >= 1 token is
+                # always recomputed — the prefill's last-token logits seed
+                # sampling (vLLM-style last-block recompute).
+                m_nodes: list = []
+                if self._prefix_cache is not None:
+                    m_nodes = self._prefix_cache.lookup(toks0)
+                    if m_nodes and len(m_nodes) * pl_ >= plen:
+                        m_nodes = m_nodes[:-1]
+                m_pages = [nd.page for nd in m_nodes]
+                mlen = len(m_pages) * pl_
+                n_fresh = n_pp_g - len(m_pages)
+                need = n_fresh + sum(
+                    -(-min(sps, r.budget or rcfg.max_new_tokens) // pl_)
+                    for r in placed)
                 if m_pages:
-                    self._dirty.update(self._alloc.release(m_pages))
-                if (not occupied and not refill_mask.any()
-                        and not resume_mask.any()):
-                    self._alloc.alloc(need, " (group placement)")  # raises
-                break  # wait for retirements to return pages
-            fresh_pages = self._alloc.alloc(n_fresh, " (group prompt)")
-            ppages = m_pages + fresh_pages
-            queue.popleft()
-            refill_mask[lane] = True
-            refill_toks[lane, :plen - mlen] = toks0[mlen:]
-            refill_lens[lane] = plen - mlen
-            refill_prefix_len[lane] = mlen
-            refill_prefix_bt[lane, :len(m_pages)] = m_pages
-            refill_page_ids[lane, :n_fresh] = fresh_pages
-            for gidx, r in enumerate(placed):
-                s = free_slots.pop(0)
-                refill_slots[lane, gidx] = s
-                refill_budgets[lane, gidx] = place(s, r, plen, ppages,
-                                                   first_ref=(gidx == 0))
-            if parked:
-                self._alloc.retain(ppages)  # the pending record's ref
-                self._pending.append({"reqs": parked, "ppages": ppages,
-                                      "plen": plen, "lane": lane,
-                                      "logits": None})
-            if self._prefix_cache is not None:
-                # chain the suffix's FULL pages into the trie (ready next
-                # round, once their prefill has retired); the partial
-                # trailing page stays group-private
-                n_full_new = plen // pl_ - len(m_pages)
-                if n_full_new > 0:
-                    self._prefix_cache.insert(
-                        m_nodes[-1] if m_nodes else None, toks0, mlen,
-                        fresh_pages[:n_full_new])
-                self.stats["prefix_hit_tokens"] += mlen
-            self.stats["prompt_tokens"] += plen
-            self.stats["prefill_tokens"] += plen - mlen
-            self.stats["prompt_prefills"] += 1
-            lane += 1
+                    # pin the match before eviction can consider those pages
+                    self._alloc.retain(m_pages)
+                    self._prefix_cache.touch(m_nodes)
+                if not self._ensure_free(need):
+                    if m_pages:
+                        self._dirty.update(self._alloc.release(m_pages))
+                    if (not occupied and not refill_mask.any()
+                            and not resume_mask.any()):
+                        self._alloc.alloc(need, " (group placement)")  # raises
+                    break  # wait for retirements to return pages
+                fresh_pages = self._alloc.alloc(n_fresh, " (group prompt)")
+                ppages = m_pages + fresh_pages
+                queue.popleft()
+                refill_mask[lane] = True
+                refill_toks[lane, :plen - mlen] = toks0[mlen:]
+                refill_lens[lane] = plen - mlen
+                refill_prefix_len[lane] = mlen
+                refill_prefix_bt[lane, :len(m_pages)] = m_pages
+                refill_page_ids[lane, :n_fresh] = fresh_pages
+                for gidx, r in enumerate(placed):
+                    s = free_slots.pop(0)
+                    refill_slots[lane, gidx] = s
+                    refill_budgets[lane, gidx] = place(s, r, plen, ppages,
+                                                       first_ref=(gidx == 0))
+                if parked:
+                    self._alloc.retain(ppages)  # the pending record's ref
+                    self._pending.append({"reqs": parked, "ppages": ppages,
+                                          "plen": plen, "lane": lane,
+                                          "logits": None})
+                if self._prefix_cache is not None:
+                    # chain the suffix's FULL pages into the trie (ready next
+                    # round, once their prefill has retired); the partial
+                    # trailing page stays group-private
+                    n_full_new = plen // pl_ - len(m_pages)
+                    if n_full_new > 0:
+                        self._prefix_cache.insert(
+                            m_nodes[-1] if m_nodes else None, toks0, mlen,
+                            fresh_pages[:n_full_new])
+                    self.stats["prefix_hit_tokens"] += mlen
+                self.stats["prompt_tokens"] += plen
+                self.stats["prefill_tokens"] += plen - mlen
+                self.stats["prompt_prefills"] += 1
+                lane += 1
 
-        if not refill_mask.any() and not resume_mask.any() and not occupied:
-            self.last_state = state  # session quiescent: expose for tests
-            return harvested
+            if (not refill_mask.any() and not resume_mask.any()
+                    and not occupied):
+                self.last_state = state  # session quiescent: expose for tests
+                return harvested
 
-        # -- block tables + free-page invalidation mask, rebuilt per round
-        bt = np.full((s_slots, self._max_pages), -1, np.int32)
-        for s in occupied:
-            n_pp_s = -(-int(self._slot_plen[s]) // pl_)
-            bt[s, :n_pp_s] = self._slot_prompt_pages[s]
-            dp = self._slot_decode_pages[s]
-            bt[s, n_pp_s:n_pp_s + len(dp)] = dp
-        free_mask = np.zeros((self.num_pages,), bool)
-        if self._dirty:
-            free_mask[sorted(self._dirty)] = True
+            # -- block tables + free-page invalidation mask, rebuilt per round
+            bt = np.full((s_slots, self._max_pages), -1, np.int32)
+            for s in occupied:
+                n_pp_s = -(-int(self._slot_plen[s]) // pl_)
+                bt[s, :n_pp_s] = self._slot_prompt_pages[s]
+                dp = self._slot_decode_pages[s]
+                bt[s, n_pp_s:n_pp_s + len(dp)] = dp
+            free_mask = np.zeros((self.num_pages,), bool)
+            if self._dirty:
+                free_mask[sorted(self._dirty)] = True
 
-        self._state = self._dispatch(
-            state, bt, free_mask, refill_toks, refill_lens,
-            refill_prefix_len, refill_prefix_bt, refill_page_ids,
-            refill_slots, refill_budgets, refill_mask, resume_slots,
-            resume_logits, resume_lens, resume_budgets, resume_mask,
-            cancel_mask)
+        with span("nat.engine.dispatch"):
+            self._state = self._dispatch(
+                state, bt, free_mask, refill_toks, refill_lens,
+                refill_prefix_len, refill_prefix_bt, refill_page_ids,
+                refill_slots, refill_budgets, refill_mask, resume_slots,
+                resume_logits, resume_lens, resume_budgets, resume_mask,
+                cancel_mask)
         self._dirty.clear()
         for s in occupied:
             self._n_gen_ub[s] = min(self._n_gen_ub[s] + sps,

@@ -57,6 +57,11 @@ def make_loss_fn(model_cfg: ModelConfig, grpo_cfg: GRPOConfig, *,
     rules = rules or DEFAULT_RULES  # a mesh without rules gets the defaults
 
     def loss_fn(params, mb: dict):
+        # the backward pass's ops carry transpose(jvp(learner.loss))
+        with jax.named_scope("learner.loss"):
+            return _loss(params, mb)
+
+    def _loss(params, mb: dict):
         if packed or paged:
             pg = {} if not paged else dict(
                 paged_prefix=mb["pool"],
@@ -106,7 +111,8 @@ def make_train_step(
     paged: bool = False,
     paged_impl: str = "ref",
 ):
-    """Returns train_step(params, opt_state, batch) -> (params', opt', metrics).
+    """Returns learner_step(params, opt_state, batch) -> (params', opt',
+    metrics); jitted, its program is named ``jit_learner_step``.
 
     With num_microbatches > 1 the batch is split on dim 0 and gradients are
     accumulated in fp32 through a lax.scan (sequential microbatches — the
@@ -163,7 +169,7 @@ def make_train_step(
                                       metrics)
         return g_acc, metric_acc
 
-    def train_step(params, opt_state, batch: dict):
+    def learner_step(params, opt_state, batch: dict):
         m = num_microbatches
         if m == 1:
             (loss, metrics), grads = vg(params, batch)
@@ -198,13 +204,14 @@ def make_train_step(
             else:
                 (grads, metrics), _ = jax.lax.scan(acc, (g0, metric0), mbs)
 
-        new_params, new_opt, opt_metrics = adamw_update(
-            params, grads, opt_state, opt_cfg)
+        with jax.named_scope("learner.optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                params, grads, opt_state, opt_cfg)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         return new_params, new_opt, metrics
 
-    return train_step
+    return learner_step
 
 
 def with_publication(train_step, publisher):

@@ -146,5 +146,5 @@ def test_rpc_repack_shrinks_learner_tokens():
     rpc = NATGRPOTrainer(cfg, NATTrainerConfig(
         selector="rpc", selector_kwargs=(("min_cut", 2),), **common))
     mf = full.train_step()
-    toks_rpc = [rpc.train_step()["learner_tokens"] for _ in range(6)]
-    assert np.mean(toks_rpc) < mf["learner_tokens"]
+    toks_rpc = [rpc.train_step()["tokens_scored"] for _ in range(6)]
+    assert np.mean(toks_rpc) < mf["tokens_scored"]
