@@ -4,8 +4,15 @@ local layers and sequence-sharded caches for long-context decode), and
 cross-attention to frontend embeddings (VLM).
 
 TPU notes (hardware adaptation):
-* GQA uses the kv-repeat scheme — queries keep a flat head axis that shards
-  cleanly over the "model" mesh axis even when kv_heads < model parallelism.
+* Dense and sharded GQA (``self_attention``, ``decode_attention``,
+  ``cross_attention``) use the kv-repeat scheme — queries keep a flat head
+  axis that shards cleanly over the "model" mesh axis even when kv_heads <
+  model parallelism.  The paged paths (``paged_decode_attention``,
+  ``paged_score_attention``) use grouped-query einsums instead: queries
+  reshaped to (KV, G) and scored against the gathered pages as they are,
+  so no KV tensor is ever widened to query-head width.  Paged decode runs
+  in rollout engines, each a whole replica (``dist/placement.py``), so its
+  heads are never sharded over a mesh axis.
 * Sliding-window prefill uses an exact two-block banded computation so HLO
   FLOPs reflect the O(T·w) cost instead of a masked O(T^2) einsum.
 * The Pallas kernel (repro.kernels.prefix_attn) implements the same math
@@ -432,14 +439,21 @@ def paged_decode_attention(
     via block-table index maps, no dense KV copy); ``"ref"`` is the jnp
     gather path.  Returns (out (S, 1, D), new_pool).
 
+    The ``"ref"`` branch scores the bf16 (S, L, KV, D) gather with
+    grouped-query einsums — q reshaped to (S, KV, G, D), G = H // KV — not
+    with ``decode_attention``'s kv-repeat: repeating the gather to every
+    query head made XLA materialize it at H width (in f32 for K, after
+    converting the whole pool), several times the KV bytes decode needs.
+    The arithmetic is ``decode_attention``'s: the same bf16 operands, f32
+    score accumulation, NEG_INF mask, one ``jax.nn.softmax``, and bf16
+    probabilities into the value product, so the paged engine reproduces
+    the dense arena to decode-parity tolerance.
+
     Two jnp references exist on purpose, not by accident: the ``"ref"``
-    branch below mirrors ``decode_attention``'s exact op sequence (same
-    einsum forms, NEG_INF mask, one ``jax.nn.softmax``) so the paged
-    engine reproduces the dense arena to decode-parity tolerance, while
-    ``kernels/paged_attn/ref.py`` mirrors the KERNEL's decomposition
-    (f32 upcast, explicit max-subtract) as its test oracle.  Folding them
-    together would couple dense-parity numerics to kernel-oracle
-    numerics.
+    branch below is that parity path, while ``kernels/paged_attn/ref.py``
+    mirrors the KERNEL's decomposition (f32 upcast, explicit
+    max-subtract) as its test oracle.  Folding them together would couple
+    dense-parity numerics to kernel-oracle numerics.
     """
     b = x.shape[0]
     h = p["wq"].shape[1]
@@ -469,13 +483,15 @@ def paged_decode_attention(
         else:
             kg, vg, posg = gather_pages(new_pool, block_tables)
             valid = (posg >= 0) & (posg <= posb)
-            kf = repeat_kv(kg, h // kvh)
-            vf = repeat_kv(vg, h // kvh)
-            s = jnp.einsum("bthd,bshd->bhts", q, kf.astype(q.dtype),
+            # group-indexed einsums on the (S, L, KV, D) gather: no kv
+            # repeat, the group a real non-contracting dim of the dot
+            q4 = q[:, 0].reshape(b, kvh, h // kvh, dh)
+            s = jnp.einsum("skgd,slkd->skgl", q4, kg.astype(q.dtype),
                            preferred_element_type=F32) * scale
             s = jnp.where(valid[:, None, None, :], s, NEG_INF)
             pa = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhts,bshd->bthd", pa.astype(vf.dtype), vf)
+            o = jnp.einsum("skgl,slkd->skgd", pa.astype(vg.dtype),
+                           vg).reshape(b, 1, h, dh)
     out = jnp.einsum("bthk,hkd->btd", o, p["wo"])
     return out, new_pool
 
@@ -545,9 +561,9 @@ def paged_score_attention(
     ``impl="kernel"`` routes through the Pallas prefill kernel (pages via
     block-table index maps, custom vjp); ``"ref"`` is the jnp gather
     path.  As with ``paged_decode_attention``, two references exist on
-    purpose: this ref mirrors the dense packed path's op sequence (same
-    einsum forms, NEG_INF mask, one ``jax.nn.softmax``) for logp parity
-    with ``score_tokens``'s dense layouts, while
+    purpose: this ref computes the dense packed path's arithmetic (NEG_INF
+    mask, one ``jax.nn.softmax``; grouped-query einsums in place of the
+    kv-repeat) for logp parity with ``score_tokens``'s dense layouts, while
     ``kernels/paged_attn/ref.py`` mirrors the KERNEL's decomposition as
     its test oracle.  Returns (out (B, T, d_model), (k, v))."""
     b, t, _ = x.shape

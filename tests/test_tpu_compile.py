@@ -130,3 +130,33 @@ def test_paged_prefill_bwd_compiles_qwen3_8b(one_chip):
     text = compiled.as_text()
     # forward + pool dq + pool dkv + packed dq + packed dkv
     assert text.count("tpu_custom_call") >= 5
+
+
+def test_paged_decode_ref_compiles_without_head_broadcast_qwen3_8b(one_chip):
+    """The jnp paged decode at the benchmark's Qwen3-8B decode shapes (80
+    slots, 34 pages of 16): the chip's compiler gets grouped-query dots on
+    the bf16 gather, so no K/V value at query-head width and no float32
+    copy of the pool (or of its gather) appear in the compiled program."""
+    from repro.models.attention import paged_decode_attention
+
+    s, m, page_len, h, kvh, d, dm = 80, 34, 16, 32, 8, 128, 4096
+    p = s * m
+    params = {"wq": _spec(one_chip, (dm, h, d), BF16),
+              "wk": _spec(one_chip, (dm, kvh, d), BF16),
+              "wv": _spec(one_chip, (dm, kvh, d), BF16),
+              "wo": _spec(one_chip, (h, d, dm), BF16)}
+    pool = {"k": _spec(one_chip, (p, page_len, kvh, d), BF16),
+            "v": _spec(one_chip, (p, page_len, kvh, d), BF16),
+            "pos": _spec(one_chip, (p, page_len), I32)}
+    fn = jax.jit(lambda *a: paged_decode_attention(*a, rope_theta=1e6))
+    text = fn.lower(params, _spec(one_chip, (s, 1, dm), BF16), pool,
+                    _spec(one_chip, (s,), I32), _spec(one_chip, (s, m), I32),
+                    _spec(one_chip, (s,), I32),
+                    _spec(one_chip, (s,), I32)).compile().as_text()
+    length = m * page_len
+    for shape in (f"[{s},{length},{h},{d}]", f"[{s},{m},{page_len},{h},{d}]",
+                  f"[{s},{length},{kvh},{h // kvh},{d}]",
+                  f"f32[{p},{page_len},{kvh},{d}]",
+                  f"f32[{s},{length},{kvh},{d}]",
+                  f"f32[{s},{m},{page_len},{kvh},{d}]"):
+        assert shape not in text, shape
