@@ -50,16 +50,16 @@ def altered_row_gap(spec, cap_steps, flat0_host, row: int) -> float:
     import numpy as np
 
     from bench import reference as R
-    from bench import weights as W
 
     s1 = cap_steps[0]
-    z = W.dims(spec["config"])
+    fam = spec["family"]
+    z = fam.dims(spec["config"])
     pl, rl = int(s1["prompt_lens"][row]), int(s1["response_lens"][row])
     toks = np.array(s1["tokens"][row:row + 1])
     toks[0, pl] = (toks[0, pl] + 1) % z["v"]
     params = {n: jnp.asarray(v).astype(jnp.float32)
               for n, v in flat0_host.items()}
-    logp = np.asarray(jax.jit(lambda p, t: R.token_logp(p, t, z))(
+    logp = np.asarray(jax.jit(lambda p, t: R.token_logp(fam, p, t, z))(
         params, jnp.asarray(toks)))
     return float(np.max(np.abs(s1["old_logp"][row, pl:pl + rl]
                                - logp[0, pl:pl + rl])))

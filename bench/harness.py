@@ -2,9 +2,12 @@
 the check of the first steps against the reference.
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
-cell's configuration and traffic mix; the configuration's ``file``, the mix
-``bench/traffic/<traffic>.json``, the limits ``bench/limits/<cell>.json``
-and each per-layer metric's reader ``bench/metrics/<metric>.py``.
+cell's configuration and traffic mix; the configuration's ``file``, the
+family module that file names under ``reference`` (its equations, weight
+layout and FLOP terms: bench/models/dense_gqa.py says what a family
+provides), the mix ``bench/traffic/<traffic>.json``, the limits
+``bench/limits/<cell>.json`` and each per-layer metric's reader
+``bench/metrics/<metric>.py``.
 
 Run sequence:
 
@@ -23,6 +26,7 @@ Run sequence:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -62,6 +66,9 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
     cell = cells[workload]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cfg = json.loads((root / cfg_entry["file"]).read_text())
+    if "reference" not in cfg:
+        raise KeyError(f"{cfg_entry['file']}: no 'reference' key naming "
+                       f"the configuration's family module")
     traffic = json.loads(
         (root / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
     t = traffic["trainer"]
@@ -72,6 +79,7 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
     return {
         "cell": cell,
         "config": cfg,
+        "family": _load_module(str(root / cfg["reference"])),
         "traffic": traffic,
         "limits": json.loads(
             (root / "bench" / "limits" / f"{workload}.json").read_text()),
@@ -82,25 +90,15 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
     }
 
 
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file: the registry
-    entry's settings with every size taken from the file."""
-    from repro.configs import get_config
-    from repro.models.config import dense_blocks
-
-    base = get_config(cfg["registry_id"])
-    mc = dataclasses.replace(
-        base, d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        blocks=dense_blocks(cfg["num_hidden_layers"]),
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"])
-    if (mc.mlp_kind != "swiglu" or mc.tie_embeddings
-            != cfg["tie_word_embeddings"] or mc.moe or mc.mla
-            or cfg["hidden_act"] != "silu"):
-        raise ValueError(f"{cfg['name']}: the reference covers dense SwiGLU "
-                         f"decoders with untied heads only")
-    return mc
+@functools.cache
+def _load_module(path: str):
+    """A module of the benchmark's, loaded by its path (one module object
+    per file, so the reference's programs compile once per family)."""
+    mod_spec = importlib.util.spec_from_file_location(
+        "bench_" + Path(path).stem.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def trainer_config(traffic: dict, seed32: int):
@@ -264,13 +262,13 @@ def follow_reference(spec: dict, seed: int, cap_steps, flat0_host,
     """The reference (or a variant of it, through ``kw``) over the captured
     set-up steps."""
     t = spec["traffic"]["trainer"]
-    z = W.dims(spec["config"])
+    fam = spec["family"]
     p = spec["traffic"]["reward"]["p"]
     kw.setdefault("temps", (t["temperature"],
                             t["temperature"] * FAULT_TEMPERATURE))
     return R.follow(
-        flat0_host, z, cap_steps, t["adamw"], t["selector"],
-        t["selector_kwargs"].get("min_cut", 0), t["group_size"],
+        fam, flat0_host, fam.dims(spec["config"]), cap_steps, t["adamw"],
+        t["selector"], t["selector_kwargs"].get("min_cut", 0), t["group_size"],
         t["grpo"]["adv_eps"], [rewards_of(s, seed, p) for s in cap_steps],
         **kw)
 
@@ -289,12 +287,7 @@ class RunRecord:
 
 
 def _load_reader(name: str, root: Path):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(str(root / "bench" / "metrics" / f"{name}.py")).read
 
 
 def say(msg: str) -> None:
@@ -317,14 +310,14 @@ def setup(spec: dict, seed: int, clog: CompileLog, warm: bool = True,
     step metrics)."""
     from repro.rl import NATGRPOTrainer
 
-    traffic = spec["traffic"]
+    traffic, fam = spec["traffic"], spec["family"]
     s32 = W.seed32(seed)
-    mc = model_config(spec["config"])
+    mc = fam.program_config(spec["config"])
     gen = TR.Traffic(traffic, seed)
-    flat0 = W.make(spec["config"], seed)
+    flat0 = W.make(fam.layout(spec["config"]), seed)
     flat0_host = jax.device_get(flat0)
     trainer = NATGRPOTrainer(mc, trainer_config(traffic, s32),
-                             params=W.to_program(flat0),
+                             params=fam.to_program(flat0),
                              budget_fn=gen.budget_fn)
     del flat0
     trainer.env = TR.RewardEnv(seed, traffic["reward"]["p"])
@@ -348,10 +341,12 @@ def setup(spec: dict, seed: int, clog: CompileLog, warm: bool = True,
         if k == 0:
             # the optimizer's first moment after one step is (1 - b1) g:
             # its norms, and a host copy for the direction
-            m1 = W.from_program(trainer.opt_state["m"])
-            grad1 = {n: v / (1 - b1) for n, v in W.leaf_norms(m1).items()}
+            m1 = fam.from_program(trainer.opt_state["m"])
+            grad1 = {n: v / (1 - b1) for n, v in W.leaf_norms(
+                m1, fam.STACKED).items()}
             m1 = jax.device_get(m1)
-    update = W.change_norms(W.from_program(trainer.params), flat0_host)
+    update = W.change_norms(fam.from_program(trainer.params), flat0_host,
+                            fam.STACKED)
     prog = {"loss": losses, "grad1": grad1, "m1": m1, "update": update,
             "ratio1": steps[0]["ratio_mean"]}
 
@@ -425,7 +420,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         f"compiles, peak {peak_bytes} bytes")
 
     rec = cap.steps[n_setup:n_setup + len(window)]
-    z = W.dims(spec["config"])
+    z = spec["family"].dims(spec["config"])
     group = spec["traffic"]["trainer"]["group_size"]
     run_rec = RunRecord(
         window=window, window_s=window_s, compiles_in_window=compiles,

@@ -1,13 +1,9 @@
-"""Plain float32 reference of what the timed step computes, for the
-configurations of ``bench/configs`` (dense decoders: GQA attention with
-RoPE, SwiGLU MLP, RMSNorm, untied head).
+"""Plain float32 reference of what the timed step computes.
 
 Written from the published equations, imports nothing of the program:
 
-* the decoder: x = E[tok]; per layer x += Attn(RMSNorm(x)), x += MLP(RMSNorm
-  (x)); logits = RMSNorm(x) W_head.  RMSNorm is x / rms(x) * (1 + offset);
-  RoPE rotates the two halves of each head (freq theta^(-2i/Dh)); query head
-  j reads key/value head j // (H / KV); SwiGLU is (silu(x Wg) * x Wu) Wd;
+* the model's forward: the configuration's family module (``logits``,
+  named by the configuration's ``reference`` key), given every matmul;
 * GRPO (NAT paper Eq. 2): advantages (r - mean) / (std + eps) per prompt,
   with the biased std;
 * the NAT objective (Eqs. 3, 6, 9) on policy: loss = -mean_i (1 / T_i)
@@ -24,8 +20,9 @@ states for them (``torch_dtype``): each update is computed in float32 and
 rounded to bfloat16, as a bfloat16 checkpoint holds it.
 
 ``fp8=True`` is the control: the same computation with every matmul's two
-operands rounded to float8 e4m3 (scaled per tensor to its largest value),
-the precision below bfloat16 that a later change could be tempted to use.
+operands (the family's too, through ``mm``) rounded to float8 e4m3 (scaled
+per tensor to its largest value), the precision below bfloat16 that a later
+change could be tempted to use.
 
 It runs on the device after the program's state is freed, a block of
 sequences at a time (each padded only to its own longest, in steps of 128),
@@ -73,53 +70,6 @@ def _mm(eq, a, b, fp8):
     return jnp.einsum(eq, a, b, precision=HI, preferred_element_type=F32)
 
 
-def _rmsnorm(x, offset, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (
-        1.0 + offset)
-
-
-def _rope(x, pos, theta):
-    """x (B, T, H, D), pos (T,)."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = pos.astype(F32)[:, None] * freq            # (T, D/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(x, p, z, fp8):
-    b, t, _ = x.shape
-    pos = jnp.arange(t)
-    h = _rmsnorm(x, p["ln1"], z["eps"])
-    q = _rope(_mm("btd,dhk->bthk", h, p["wq"], fp8), pos, z["theta"])
-    k = _rope(_mm("btd,dhk->bthk", h, p["wk"], fp8), pos, z["theta"])
-    v = _mm("btd,dhk->bthk", h, p["wv"], fp8)
-    g = z["h"] // z["kv"]
-    q = q.reshape(b, t, z["kv"], g, z["dh"])
-    s = _mm("btngk,bsnk->bngts", q, k, fp8) / math.sqrt(z["dh"])
-    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    a = jax.nn.softmax(s, axis=-1)
-    o = _mm("bngts,bsnk->btngk", a, v, fp8).reshape(b, t, z["h"], z["dh"])
-    x = x + _mm("bthk,hkd->btd", o, p["wo"], fp8)
-    h = _rmsnorm(x, p["ln2"], z["eps"])
-    u = jax.nn.silu(_mm("btd,df->btf", h, p["w_gate"], fp8)) * _mm(
-        "btd,df->btf", h, p["w_up"], fp8)
-    return x + _mm("btf,fd->btd", u, p["w_down"], fp8)
-
-
-def _logits(params, tokens, z, fp8):
-    """(B, T - 1, V) logits of each next token."""
-    x = params["embed"][tokens]
-    for i in range(z["layers"]):
-        p = {k[len("layers."):]: v[i] for k, v in params.items()
-             if k.startswith("layers.")}
-        x = jax.checkpoint(partial(_layer, z=z, fp8=fp8))(x, p)
-    h = _rmsnorm(x, params["final_norm"], z["eps"])
-    return _mm("btd,dv->btv", h[:, :-1], params["head"], fp8)
-
-
 def _at_tokens(per_pos, tokens=None):
     """(B, T - 1[, V]) per-position values -> (B, T) at each token (0 at
     position 0); with ``tokens``, the entry of the token that follows."""
@@ -129,10 +79,16 @@ def _at_tokens(per_pos, tokens=None):
     return jnp.concatenate([jnp.zeros_like(per_pos[:, :1]), per_pos], axis=1)
 
 
-def token_logp(params, tokens, z, fp8=False):
+def _logits(family, params, tokens, z, fp8):
+    """The family's (B, T - 1, V) logits, every matmul through ``_mm``."""
+    return family.logits(params, tokens, z, partial(_mm, fp8=fp8))
+
+
+def token_logp(family, params, tokens, z, fp8=False):
     """(B, T) log-probability of each token given the ones before it (0 at
     position 0).  ``params``: flat float32 weights (bench/weights.py)."""
-    lp = jax.nn.log_softmax(_logits(params, tokens, z, fp8), axis=-1)
+    lp = jax.nn.log_softmax(_logits(family, params, tokens, z, fp8),
+                            axis=-1)
     return _at_tokens(lp, tokens)
 
 
@@ -152,14 +108,14 @@ def sampler_terms(logits, tokens, temps):
             "ef": _at_tokens(-jnp.sum(q * lp, -1))}
 
 
-@partial(jax.jit, static_argnames=("zt", "fp8", "temps"),
+@partial(jax.jit, static_argnames=("family", "zt", "fp8", "temps"),
          donate_argnums=(1,))
-def _block(params, acc, tokens, weights, adv, inv_len, scale, zt, fp8,
-           temps):
+def _block(params, acc, tokens, weights, adv, inv_len, scale, family, zt,
+           fp8, temps):
     z = dict(zt)
 
     def loss_fn(pf):
-        logits = _logits(pf, tokens, z, fp8)
+        logits = _logits(family, pf, tokens, z, fp8)
         logp = _at_tokens(jax.nn.log_softmax(logits, axis=-1), tokens)
         rho = jnp.exp(logp - jax.lax.stop_gradient(logp))
         per_seq = jnp.sum(weights * adv[:, None] * rho, axis=1) * inv_len
@@ -261,8 +217,8 @@ def cut_z(stats) -> float:
 
 
 # ------------------------------------------------------------------ steps
-def follow(flat0, z, batches, hp, selector, min_cut, group, adv_eps,
-           rewards, *, fp8=False, rows=None, temps=(1.0, 0.8),
+def follow(family, flat0, z, batches, hp, selector, min_cut, group,
+           adv_eps, rewards, *, fp8=False, rows=None, temps=(1.0, 0.8),
            grad1_other=None, keep_grad1=False) -> dict:
     """Run the reference (or, with ``fp8``, the control) through the
     captured steps.
@@ -319,7 +275,7 @@ def follow(flat0, z, batches, hp, selector, min_cut, group, adv_eps,
                 jnp.asarray((w[sel, :t] * live[:, None]).astype(np.float32)),
                 jnp.asarray(adv[sel].astype(np.float32)),
                 jnp.asarray(inv_len[sel]),
-                jnp.float32(1.0 / len(use)), zt, fp8, tuple(temps))
+                jnp.float32(1.0 / len(use)), family, zt, fp8, tuple(temps))
             loss += float(l)
             lp, ps = np.asarray(lp), np.asarray(ps)
             logp[blk, :t] = lp[:len(blk)]
@@ -335,9 +291,10 @@ def follow(flat0, z, batches, hp, selector, min_cut, group, adv_eps,
         factor = jnp.float32(min(1.0, hp["clip_norm"] / max(gnorm, 1e-12)))
         if k == 1:
             out["grad1"] = W.leaf_norms(
-                {n: acc[n] * factor for n in acc})
+                {n: acc[n] * factor for n in acc}, family.STACKED)
             if grad1_other is not None:
-                out["grad1_cos"] = W.cosines(acc, grad1_other)
+                out["grad1_cos"] = W.cosines(acc, grad1_other,
+                                             family.STACKED)
             if keep_grad1:
                 out["grad1_host"] = {n: np.asarray(jax.device_get(
                     acc[n] * factor)) for n in acc}
@@ -356,5 +313,5 @@ def follow(flat0, z, batches, hp, selector, min_cut, group, adv_eps,
             moments[n] = (np.asarray(m), np.asarray(v))
             new[n] = p
         params = new
-    out["update"] = W.change_norms(params, flat0)
+    out["update"] = W.change_norms(params, flat0, family.STACKED)
     return out

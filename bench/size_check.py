@@ -38,11 +38,12 @@ def record_round_operands(spec: dict) -> list:
     from bench import weights as W
     from repro.rl import NATGRPOTrainer
 
+    fam = spec["family"]
     cfg = dict(spec["config"], **SMALL)
     gen = TR.Traffic(spec["traffic"], 0)
-    tr = NATGRPOTrainer(H.model_config(cfg),
+    tr = NATGRPOTrainer(fam.program_config(cfg),
                         H.trainer_config(spec["traffic"], 0),
-                        params=W.to_program(W.make(cfg, 0)),
+                        params=fam.to_program(W.make(fam.layout(cfg), 0)),
                         budget_fn=gen.budget_fn)
     seen = []
     step = tr.engine._step
@@ -79,17 +80,18 @@ def compile_cell(name: str) -> dict:
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
 
-    cfg, traffic = spec["config"], spec["traffic"]
-    mc = H.model_config(cfg)
+    cfg, traffic, fam = spec["config"], spec["traffic"], spec["family"]
+    mc = fam.program_config(cfg)
     tcfg = H.trainer_config(traffic, 0)
-    flat = {k: sds(shape, W.STORE) for k, (shape, _) in W.layout(cfg).items()}
-    params = W.to_program(flat)
+    flat = {k: sds(shape, W.STORE)
+            for k, (shape, _) in fam.layout(cfg).items()}
+    params = fam.to_program(flat)
 
     rows = traffic["warm_rows"][1]
     batch = {k: sds((rows,) + shp[1:] if len(shp) >= 2 else shp, dt)
              for k, (shp, dt) in batch_shapes.items()}
     f32 = {k: sds(v.shape, jnp.float32) for k, v in flat.items()}
-    opt = {"m": W.to_program(f32), "v": W.to_program(dict(f32)),
+    opt = {"m": fam.to_program(f32), "v": fam.to_program(dict(f32)),
            "step": sds((), jnp.int32)}
     step = jax.jit(make_train_step(mc, tcfg.grpo, tcfg.adamw, vocab_chunks=1,
                                    packed=True), donate_argnums=(1,))

@@ -6,7 +6,10 @@ harness's functions directly, with the cell's own files and limits, and
 the model (two layers of width 1024, heads of 128) and the lengths cut
 down."""
 import dataclasses
+import json
+import shutil
 import time
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -18,8 +21,8 @@ CELL = "qwen3_8b.cot_rpc"
 SEED = 2**31 + 12345             # seeds may exceed 32 signed bits
 
 
-def small_spec():
-    spec = H.load_spec(CELL)
+def small_spec(cell=CELL, root=H.ROOT):
+    spec = H.load_spec(cell, root)
     spec["config"].update(hidden_size=1024, intermediate_size=3072,
                           num_attention_heads=8, num_key_value_heads=2,
                           head_dim=128, num_hidden_layers=2, vocab_size=2048)
@@ -182,3 +185,67 @@ def test_control_and_planted_faults_fail_the_limits():
     assert fails(got["wrong_temperature"]) == ["sample_z"]
     assert fails(got["cut_early"]) == ["cut_z"]
     assert "select_faults" in fails(got["not_prefix"]), got["not_prefix"]
+
+
+NEW_CELL = "qwen3_8b_copy.cot_rpc"
+
+
+def _root_with_a_new_family(tmp: Path, reference) -> Path:
+    """A root holding a copy of BENCHMARK.json with one configuration and
+    one cell added, their traffic and limits files, and a copy of the dense
+    family under another name, named by the configuration's ``reference``
+    (left out where ``reference`` is None)."""
+    bench = json.loads((H.ROOT / "BENCHMARK.json").read_text())
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    cfg = json.loads((H.ROOT / entry["file"]).read_text())
+    cfg.pop("reference")
+    if reference is not None:
+        cfg["reference"] = reference
+    name = "qwen3_8b_l4_copy"
+    files = {f"bench/configs/{name}.json": json.dumps(cfg),
+             "BENCHMARK.json": json.dumps(dict(
+                 bench,
+                 configs=bench["configs"] + [dict(
+                     entry, name=name, file=f"bench/configs/{name}.json")],
+                 workloads=bench["workloads"] + [dict(
+                     cell, name=NEW_CELL, config=name)]))}
+    for rel, text in files.items():
+        (tmp / rel).parent.mkdir(parents=True, exist_ok=True)
+        (tmp / rel).write_text(text)
+    for src, dst in (
+            ("bench/traffic/cot_rpc.json", "bench/traffic/cot_rpc.json"),
+            (f"bench/limits/{CELL}.json", f"bench/limits/{NEW_CELL}.json"),
+            ("bench/models/dense_gqa.py", "bench/models/dense_copy.py")):
+        (tmp / dst).parent.mkdir(parents=True, exist_ok=True)
+        shutil.copyfile(H.ROOT / src, tmp / dst)
+    return tmp
+
+
+def _bench_files() -> dict:
+    """Every file under the real bench/ with its size and time of change;
+    the interpreter's bytecode caches left out."""
+    return {p: (p.stat().st_size, p.stat().st_mtime_ns)
+            for p in (H.ROOT / "bench").rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+@pytest.mark.parametrize("fault", [None, _token_altered],
+                         ids=["sound", "token_altered"])
+def test_a_new_family_is_new_files_only(tmp_path, monkeypatch, fault):
+    root = _root_with_a_new_family(tmp_path, "bench/models/dense_copy.py")
+    before = _bench_files()
+    spec = small_spec(NEW_CELL, root)
+    assert spec["family"].__file__ == str(root / "bench/models/dense_copy.py")
+    if fault is not None:
+        fault(monkeypatch)
+    out = H.run(NEW_CELL, SEED, 1.0, False, t_start=time.perf_counter(),
+                root=root, spec=spec, peak_flops=1e12)
+    assert out["correct"] is (fault is None), out["checks"]
+    assert _bench_files() == before
+
+
+def test_a_configuration_without_its_family_is_refused(tmp_path):
+    root = _root_with_a_new_family(tmp_path, None)
+    with pytest.raises(KeyError, match="'reference'"):
+        H.load_spec(NEW_CELL, root)

@@ -7,13 +7,16 @@ import numpy as np
 import pytest
 
 from bench import flops as F
+from bench import harness as H
 from bench import peaks
 from bench import trace as T
 from bench import traffic as TR
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).parent / "data"
-SMALL = dict(d=64, h=4, kv=2, dh=16, f=192, v=256, layers=2)
+SMALL = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+             head_dim=16, intermediate_size=192, vocab_size=256,
+             num_hidden_layers=2, rope_theta=1e6, rms_norm_eps=1e-6)
 
 
 def test_peaks_refuse_an_unknown_device():
@@ -23,20 +26,23 @@ def test_peaks_refuse_an_unknown_device():
 
 
 def test_flops_match_a_hand_count():
+    fam = H._load_module(str(ROOT / "bench/models/dense_gqa.py"))
+    z = fam.dims(SMALL)
     # per layer: q and o 64*4*16 each, k and v 64*2*16 each, MLP 3*64*192
     per_layer = 2 * 4096 + 2 * 2048 + 3 * 12288
     n = 2 * per_layer + 64 * 256
-    assert F.matmul_params(SMALL) == n
+    assert z["n"] == n
     attn = 4 * 4 * 16 * 2          # per key attended, over both layers
+    assert z["attn"] == attn
     # one prompt of 3 tokens (positions 0..2) and its kept response of 2
     # tokens (positions 3, 4), a group of one
     want = 2 * n * 5 + attn * (1 + 2 + 3 + 4 + 5)
-    assert F.rollout_flops(SMALL, [3], [2], group=1) == want
+    assert F.rollout_flops(z, [3], [2], group=1) == want
     # a hull of 4 tokens: 6N per token, three times the attention term
-    assert F.learner_flops(SMALL, [4]) == 6 * n * 4 + 3 * attn * (
+    assert F.learner_flops(z, [4]) == 6 * n * 4 + 3 * attn * (
         1 + 2 + 3 + 4)
     # prompts are counted once per group
-    two = F.rollout_flops(SMALL, [3, 3], [2, 2], group=2)
+    two = F.rollout_flops(z, [3, 3], [2, 2], group=2)
     assert two == want + 2 * n * 2 + attn * (4 + 5)
 
 
